@@ -85,6 +85,7 @@ def main(argv=None):
     env = {
         **os.environ,
         "XLA_FLAGS": "--xla_force_host_platform_device_count=8",
+        "JAX_PLATFORMS": "cpu",  # virtual devices: a CPU measurement
         "PYTHONPATH": str(ROOT / "src"),
     }
     proc = subprocess.run(

@@ -320,6 +320,7 @@ def ring_series(reps: int) -> list[dict]:
     env = {
         **os.environ,
         "XLA_FLAGS": "--xla_force_host_platform_device_count=8",
+        "JAX_PLATFORMS": "cpu",  # virtual devices: a CPU measurement
         "PYTHONPATH": str(ROOT / "src"),
     }
     proc = subprocess.run(
@@ -338,6 +339,7 @@ def run(devices: int, msg_lens: list[int], reps: int) -> list[dict]:
     env = {
         **os.environ,
         "XLA_FLAGS": f"--xla_force_host_platform_device_count={devices}",
+        "JAX_PLATFORMS": "cpu",  # virtual devices: a CPU measurement
         "PYTHONPATH": str(ROOT / "src"),
     }
     proc = subprocess.run(
@@ -573,7 +575,7 @@ def main(argv=None):
          "print('RESULT ' + json.dumps(serving_series(int(sys.argv[1]))))",
          str(args.reps)],
         capture_output=True, text=True, timeout=1800, cwd=str(ROOT),
-        env={**os.environ, "PYTHONPATH": str(ROOT / "src")},
+        env={**os.environ, "PYTHONPATH": str(ROOT / "src"), "JAX_PLATFORMS": "cpu"},
     )
     if proc.returncode != 0:
         raise RuntimeError(proc.stderr[-3000:])

@@ -80,9 +80,11 @@ def main(argv=None):
     env = {
         **os.environ,
         "XLA_FLAGS": "--xla_force_host_platform_device_count=8",
+        "JAX_PLATFORMS": "cpu",  # virtual devices: a CPU measurement
         "PYTHONPATH": str(ROOT / "src"),
     }
     rows = []
+    failed = []
     for arch in args.archs:
         proc = subprocess.run(
             [sys.executable, "-c", CHILD, arch, str(args.steps), str(args.batch),
@@ -92,6 +94,7 @@ def main(argv=None):
         )
         if proc.returncode != 0:
             print(f"{arch}: FAILED\n{proc.stderr[-1500:]}")
+            failed.append(arch)
             continue
         for line in proc.stdout.splitlines():
             if line.startswith("RESULT "):
@@ -102,7 +105,7 @@ def main(argv=None):
     OUT.mkdir(parents=True, exist_ok=True)
     name = "train_throughput_ring.json" if args.ring > 1 else "train_throughput.json"
     (OUT / name).write_text(json.dumps(rows, indent=1))
-    return 0
+    return 1 if failed else 0
 
 
 if __name__ == "__main__":

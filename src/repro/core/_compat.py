@@ -1,10 +1,8 @@
-"""JAX version compatibility for the mesh/shard_map substrate.
+"""The mesh/shard_map substrate, on the installed JAX (0.9).
 
-The interface targets current JAX (``jax.shard_map``, ``check_vma``,
-``jax.sharding.AxisType``) but must also run on older installs where
-``shard_map`` lives in ``jax.experimental`` (``check_rep``) and ``make_mesh``
-has no ``axis_types``.  Everything that builds a mesh or enters SPMD routes
-through here so the rest of the codebase stays version-free.
+Everything that builds a mesh or enters SPMD routes through here, so the
+choices made once — ``Auto`` axis types, caller-ordered device arrays,
+``shard_map`` without varying-manual-axes checking — hold repo-wide.
 """
 
 from __future__ import annotations
@@ -12,9 +10,7 @@ from __future__ import annotations
 from typing import Any, Callable, Sequence
 
 import jax
-
-_HAS_AXIS_TYPE = hasattr(jax.sharding, "AxisType")
-_HAS_TOPLEVEL_SHARD_MAP = hasattr(jax, "shard_map")
+from jax.sharding import AbstractMesh, AxisType
 
 
 def make_mesh(
@@ -23,20 +19,14 @@ def make_mesh(
     *,
     devices: Sequence[Any] | None = None,
 ) -> jax.sharding.Mesh:
-    """``jax.make_mesh`` with ``Auto`` axis types where supported."""
+    """``jax.make_mesh`` with ``Auto`` axis types."""
 
-    shape, axis_names = tuple(shape), tuple(axis_names)
-    if _HAS_AXIS_TYPE:
-        try:
-            return jax.make_mesh(
-                shape,
-                axis_names,
-                devices=devices,
-                axis_types=(jax.sharding.AxisType.Auto,) * len(shape),
-            )
-        except TypeError:  # axis_types kwarg not accepted on this version
-            pass
-    return jax.make_mesh(shape, axis_names, devices=devices)
+    return jax.make_mesh(
+        tuple(shape),
+        tuple(axis_names),
+        axis_types=(AxisType.Auto,) * len(shape),
+        devices=devices,
+    )
 
 
 def mesh_from_devices(device_array, axis_names: Sequence[str]) -> jax.sharding.Mesh:
@@ -44,31 +34,17 @@ def mesh_from_devices(device_array, axis_names: Sequence[str]) -> jax.sharding.M
     caller's device order exactly (``make_mesh`` may reorder for physical
     topology, which would break group-rank ↔ device contracts)."""
 
-    axis_names = tuple(axis_names)
-    if _HAS_AXIS_TYPE:
-        try:
-            return jax.sharding.Mesh(
-                device_array,
-                axis_names,
-                axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names),
-            )
-        except TypeError:
-            pass
-    return jax.sharding.Mesh(device_array, axis_names)
+    return jax.sharding.Mesh(
+        device_array,
+        tuple(axis_names),
+        axis_types=(AxisType.Auto,) * len(axis_names),
+    )
 
 
-def abstract_mesh(
-    shape: Sequence[int], axis_names: Sequence[str]
-) -> "jax.sharding.AbstractMesh":
-    """``AbstractMesh`` across the (axis_sizes, axis_names) /
-    tuple-of-(name, size)-pairs signature change."""
+def abstract_mesh(shape: Sequence[int], axis_names: Sequence[str]) -> AbstractMesh:
+    """A device-free ``AbstractMesh`` of ``shape`` over ``axis_names``."""
 
-    from jax.sharding import AbstractMesh
-
-    try:
-        return AbstractMesh(tuple(shape), tuple(axis_names))
-    except TypeError:
-        return AbstractMesh(tuple(zip(axis_names, shape)))
+    return AbstractMesh(tuple(shape), tuple(axis_names))
 
 
 def shard_map(
@@ -78,20 +54,8 @@ def shard_map(
     in_specs: Any,
     out_specs: Any,
 ) -> Callable:
-    """``shard_map`` without replication/varying-manual-axes checking,
-    wherever the implementation lives on this JAX."""
+    """``jax.shard_map`` without varying-manual-axes checking."""
 
-    if _HAS_TOPLEVEL_SHARD_MAP:
-        try:
-            return jax.shard_map(
-                fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
-            )
-        except TypeError:  # exported before the check_rep -> check_vma rename
-            return jax.shard_map(
-                fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False
-            )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(
-        fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False
+    return jax.shard_map(
+        fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
     )

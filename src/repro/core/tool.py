@@ -29,14 +29,50 @@ from repro.analysis import events as analysis_events
 from repro.core import errors
 
 # --------------------------------------------------------------------------
-# hardware model (TPU v5e, per task statement)
+# hardware model: published per-chip peaks, keyed by jax device_kind
 # --------------------------------------------------------------------------
 
-PEAK_FLOPS_BF16 = 197e12     # FLOP/s per chip
-HBM_BANDWIDTH = 819e9        # bytes/s per chip
-ICI_BANDWIDTH = 50e9         # bytes/s per link
+
+@dataclasses.dataclass(frozen=True)
+class ChipPeaks:
+    flops_bf16: float        # FLOP/s per chip
+    hbm_bandwidth: float     # bytes/s per chip
+    hbm_bytes: int           # HBM capacity per chip
+    ici_bandwidth: float     # bytes/s per ICI link
+
+
+#: Source: Google Cloud documentation, "TPU v5e" (per chip: 197 TFLOP/s
+#: bf16, 16 GB HBM at 819 GB/s, 1,600 Gbit/s ICI over four links).
+CHIP_PEAKS: dict[str, ChipPeaks] = {
+    "TPU v5 lite": ChipPeaks(
+        flops_bf16=197e12,
+        hbm_bandwidth=819e9,
+        hbm_bytes=16 * 1024**3,
+        ici_bandwidth=50e9,
+    ),
+}
+
+
+def chip_peaks(device_kind: str) -> ChipPeaks:
+    """The peaks of a chip by its ``jax.Device.device_kind``; a kind with no
+    published entry is an error, never a default."""
+
+    errors.check(
+        device_kind in CHIP_PEAKS,
+        errors.ErrorClass.ERR_ARG,
+        f"no published peaks for device kind {device_kind!r} "
+        f"(known: {sorted(CHIP_PEAKS)})",
+    )
+    return CHIP_PEAKS[device_kind]
+
+
+# the roofline model and the autotuner target a TPU v5e deployment
+_TARGET = chip_peaks("TPU v5 lite")
+PEAK_FLOPS_BF16 = _TARGET.flops_bf16
+HBM_BANDWIDTH = _TARGET.hbm_bandwidth
+ICI_BANDWIDTH = _TARGET.ici_bandwidth
+HBM_BYTES = _TARGET.hbm_bytes
 DCN_BANDWIDTH = 12.5e9       # bytes/s per host NIC (inter-slice collectives)
-HBM_BYTES = 16 * 1024**3     # HBM capacity per chip
 COLLECTIVE_LAUNCH_S = 3e-6   # fixed per-collective launch/latency cost
 
 # --------------------------------------------------------------------------

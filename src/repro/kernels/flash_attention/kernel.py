@@ -28,6 +28,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import checked_interpret
+
 NEG_INF = -1e30
 
 DEFAULT_BLOCK_Q = 512
@@ -132,12 +134,12 @@ def flash_attention_fwd(
     scale: float | None = None,
     block_q: int = DEFAULT_BLOCK_Q,
     block_k: int = DEFAULT_BLOCK_K,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jax.Array:
     """q: (b, sq, h, d); k/v: (b, sk, hk, d), h % hk == 0.  → (b, sq, h, d).
 
-    ``interpret=True`` executes the kernel body in Python (CPU validation);
-    on TPU pass ``interpret=False``.
+    ``interpret=True`` executes the kernel body in Python (CPU validation
+    only; refused on a TPU backend).
     """
 
     b, sq, h, d = q.shape
@@ -198,7 +200,7 @@ def flash_attention_fwd(
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=checked_interpret(interpret),
     )(qt, kt, vt)
     out = out.transpose(0, 2, 1, 3)
     return out[:, :sq] if pad_q else out

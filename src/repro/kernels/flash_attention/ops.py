@@ -26,7 +26,7 @@ def _flash(q, k, v, causal, sliding_window, prefix_len, logit_softcap, scale, im
 
 
 def _forward(q, k, v, causal, sliding_window, prefix_len, logit_softcap, scale, impl):
-    if impl == "pallas":
+    if impl in ("pallas", "pallas_tpu"):
         return _kernel.flash_attention_fwd(
             q,
             k,
@@ -36,19 +36,7 @@ def _forward(q, k, v, causal, sliding_window, prefix_len, logit_softcap, scale, 
             prefix_len=prefix_len,
             logit_softcap=logit_softcap,
             scale=scale,
-            interpret=True,
-        )
-    if impl == "pallas_tpu":
-        return _kernel.flash_attention_fwd(
-            q,
-            k,
-            v,
-            causal=causal,
-            sliding_window=sliding_window,
-            prefix_len=prefix_len,
-            logit_softcap=logit_softcap,
-            scale=scale,
-            interpret=False,
+            interpret=impl == "pallas",
         )
     return _ref.mha(
         q,
@@ -106,7 +94,7 @@ def flash_attention(
     'ref'      — O(S²) pure jnp (small shapes, oracle);
     'chunked'  — online-softmax jnp, O(S·block) memory (production XLA path,
                  differentiated directly: the scan already avoids S² residuals);
-    'pallas'   — interpret-mode kernel (CPU validation);
+    'pallas'   — interpret-mode kernel (CPU validation; an error on TPU);
     'pallas_tpu' — the TPU kernel."""
 
     if impl == "chunked":

@@ -13,6 +13,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.core.compress import BLOCK
+from repro.kernels import checked_interpret
 
 ROW_TILE = 64
 
@@ -27,7 +28,7 @@ def _quant_kernel(x_ref, q_ref, s_ref):
 
 
 def quantize_int8_rows(
-    x: jax.Array, *, row_tile: int = ROW_TILE, interpret: bool = True
+    x: jax.Array, *, row_tile: int = ROW_TILE, interpret: bool = False
 ) -> tuple[jax.Array, jax.Array]:
     """x: (rows, BLOCK) fp — returns (int8 (rows, BLOCK), fp32 scales (rows, 1))."""
 
@@ -48,7 +49,7 @@ def quantize_int8_rows(
             jax.ShapeDtypeStruct((rows, BLOCK), jnp.int8),
             jax.ShapeDtypeStruct((rows, 1), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=checked_interpret(interpret),
     )(x)
     return q, s
 
@@ -59,7 +60,7 @@ def _dequant_kernel(q_ref, s_ref, x_ref):
 
 def dequantize_int8_rows(
     q: jax.Array, s: jax.Array, *, out_dtype=jnp.float32, row_tile: int = ROW_TILE,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jax.Array:
     rows, width = q.shape
     assert width == BLOCK
@@ -74,5 +75,5 @@ def dequantize_int8_rows(
         ],
         out_specs=pl.BlockSpec((row_tile, BLOCK), lambda r: (r, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, BLOCK), out_dtype),
-        interpret=interpret,
+        interpret=checked_interpret(interpret),
     )(q, s)

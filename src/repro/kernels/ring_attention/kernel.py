@@ -42,6 +42,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import checked_interpret
+
 NEG_INF = -1e30
 
 DEFAULT_BLOCK_Q = 512
@@ -138,14 +140,15 @@ def ring_step_fwd(
     causal: bool = True,
     block_q: int = DEFAULT_BLOCK_Q,
     block_k: int = DEFAULT_BLOCK_K,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """One ring step: fold ``softmax(q @ k.T) @ v`` of this KV block into
     the carry.  Returns the updated ``(m, l, acc)``.
 
     Sequence lengths must already be block multiples (the ops layer pads
     once, outside the ring loop; ``kv_len`` masks the padded tail).
-    ``interpret=True`` runs the kernel body in Python (CPU validation).
+    ``interpret=True`` runs the kernel body in Python (CPU validation only;
+    refused on a TPU backend).
     """
 
     b, h, sq, d = q.shape
@@ -198,7 +201,7 @@ def ring_step_fwd(
             jax.ShapeDtypeStruct((b, h, sq, 1), jnp.float32),
             jax.ShapeDtypeStruct((b, h, sq, d), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=checked_interpret(interpret),
     )(info, q, k, v, m, l, acc)
 
 

@@ -9,9 +9,11 @@ TPU adaptation of the SSD algorithm:
 * each chunk step is three MXU matmuls (``C Bᵀ``, ``(CB ⊙ L) X``,
   ``Xᵀ_w B``) plus VPU elementwise decay math — the "duality" that makes
   SSM training MXU-bound instead of scan-bound;
-* ``BlockSpec`` tiles: x/y ``(chunk, p)``, B/C ``(chunk, n)`` with the
-  group index derived from the head grid index (grouped B/C need no
-  materialised repeat);
+* ``BlockSpec`` tiles over head-major operands: x/y ``(chunk, p)``, B/C
+  ``(chunk, n)`` with the group index derived from the head grid index
+  (grouped B/C need no materialised repeat), dt and the within-chunk
+  cumsum of ``dt·A`` each as a ``(chunk, 1)`` column and a ``(1, chunk)``
+  row;
 * default ``chunk=128`` keeps every matmul MXU-aligned and the working set
   (≈ 4·chunk·max(p,n) fp32) far below VMEM.
 """
@@ -25,15 +27,19 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import checked_interpret
+
 
 def _ssd_kernel(
-    a_ref,      # (1,)        A for this head
-    x_ref,      # (1, q, 1, p)
-    dt_ref,     # (1, q, 1)
-    b_ref,      # (1, q, 1, n)
-    c_ref,      # (1, q, 1, n)
-    y_ref,      # (1, q, 1, p)
-    state_ref,  # VMEM (p, n) fp32 carry
+    x_ref,       # (1, 1, q, p)
+    dtc_ref,     # (1, 1, q, 1)   dt as a column
+    dtr_ref,     # (1, 1, 1, q)   dt as a row
+    cumc_ref,    # (1, 1, q, 1)   within-chunk inclusive cumsum of dt·A, column
+    cumr_ref,    # (1, 1, 1, q)   the same as a row
+    b_ref,       # (1, 1, q, n)
+    c_ref,       # (1, 1, q, n)
+    y_ref,       # (1, 1, q, p)
+    state_ref,   # VMEM (p, n) fp32 carry
     *,
     chunk: int,
 ):
@@ -43,46 +49,42 @@ def _ssd_kernel(
     def _init():
         state_ref[...] = jnp.zeros_like(state_ref)
 
-    a = a_ref[0].astype(jnp.float32)
-    x = x_ref[0, :, 0, :].astype(jnp.float32)      # (q, p)
-    dt = dt_ref[0, :, 0].astype(jnp.float32)       # (q,)
-    B = b_ref[0, :, 0, :].astype(jnp.float32)      # (q, n)
-    C = c_ref[0, :, 0, :].astype(jnp.float32)      # (q, n)
+    x = x_ref[0, 0].astype(jnp.float32)            # (q, p)
+    dt_col = dtc_ref[0, 0].astype(jnp.float32)     # (q, 1)
+    dt_row = dtr_ref[0, 0].astype(jnp.float32)     # (1, q)
+    cum_col = cumc_ref[0, 0]                        # (q, 1)
+    cum_row = cumr_ref[0, 0]                        # (1, q)
+    total = cum_row[:, chunk - 1:]                  # (1, 1)
+    B = b_ref[0, 0].astype(jnp.float32)            # (q, n)
+    C = c_ref[0, 0].astype(jnp.float32)            # (q, n)
 
-    dA = dt * a
-    cum = jnp.cumsum(dA)                            # (q,) inclusive
-    total = cum[-1]
+    def mm(lhs, rhs, contract):
+        return jax.lax.dot_general(
+            lhs, rhs, (contract, ((), ())), preferred_element_type=jnp.float32
+        )
 
-    # intra-chunk: (C Bᵀ ⊙ L) X
-    cb = jax.lax.dot_general(
-        C, B, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    )                                               # (q, q)
-    # mask the exponent (not the exp result): above the diagonal cum_i-cum_j
-    # is positive and exp() overflows, which would poison autodiff through
-    # the interpret-mode kernel with inf·0 (same fix as ref.ssd_chunked).
     qi = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
     kj = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    seg = jnp.where(qi >= kj, cum[:, None] - cum[None, :], -jnp.inf)
-    L = jnp.exp(seg) * dt[None, :]
-    y_intra = jax.lax.dot_general(
-        cb * L, x, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    )                                               # (q, p)
+    lower = qi >= kj
+
+    # intra-chunk: (C Bᵀ ⊙ L) X.  Mask the exponent (not the exp result):
+    # above the diagonal cum_i-cum_j is positive and exp() overflows, which
+    # would poison autodiff through the kernel with inf·0 (same fix as
+    # ref.ssd_chunked).
+    cb = mm(C, B, ((1,), (1,)))                     # (q, q)
+    seg = jnp.where(lower, cum_col - cum_row, -jnp.inf)
+    L = jnp.exp(seg) * dt_row
+    y_intra = mm(cb * L, x, ((1,), (0,)))           # (q, p)
 
     # inter-chunk: exp(cum_i) * (H_in C_i)
     h_in = state_ref[...]                           # (p, n)
-    y_inter = jnp.exp(cum)[:, None] * jax.lax.dot_general(
-        C, h_in, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    )                                               # (q, p)
+    y_inter = jnp.exp(cum_col) * mm(C, h_in, ((1,), (1,)))  # (q, p)
 
     # state update: H = exp(total) H_in + Xᵀ_w B
-    w = jnp.exp(total - cum) * dt                   # (q,)
-    xw = x * w[:, None]                             # (q, p)
-    s_local = jax.lax.dot_general(
-        xw, B, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    )                                               # (p, n)
-    state_ref[...] = jnp.exp(total) * h_in + s_local
+    xw = x * (jnp.exp(total - cum_col) * dt_col)    # (q, p)
+    state_ref[...] = jnp.exp(total) * h_in + mm(xw, B, ((0,), (0,)))
 
-    y_ref[0, :, 0, :] = (y_intra + y_inter).astype(y_ref.dtype)
+    y_ref[0, 0] = (y_intra + y_inter).astype(y_ref.dtype)
 
 
 def ssd_scan_fwd(
@@ -93,10 +95,15 @@ def ssd_scan_fwd(
     C: jax.Array,    # (b, l, g, n)
     *,
     chunk: int = 128,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jax.Array:
     """Chunked SSD scan; returns y (b, l, h, p).  Zero initial state (the
-    training/prefill case; decoding uses the explicit-state step in ref)."""
+    training/prefill case; decoding uses the explicit-state step in ref).
+
+    The operands go head-major once, outside the kernel, so every block's
+    two minor dims are ``(chunk, p|n|1)`` or ``(1, chunk)`` — the tiling
+    Mosaic accepts.  ``interpret=True`` runs the Pallas interpreter (CPU
+    validation only; refused on a TPU backend)."""
 
     b, l, h, p = x.shape
     g, n = B.shape[2], B.shape[3]
@@ -104,23 +111,46 @@ def ssd_scan_fwd(
     nc = l // chunk
     group = h // g
 
+    xt = x.transpose(0, 2, 1, 3)                    # (b, h, l, p)
+    dtt = dt.transpose(0, 2, 1)                     # (b, h, l)
+    # Mosaic has no cumsum: XLA takes the within-chunk cumsum of dt·A here,
+    # in fp32 as the reference does, and the kernel reads it in both
+    # orientations (every value stays 2-D, so nothing is transposed inside)
+    dA = dtt.astype(jnp.float32) * A.astype(jnp.float32)[None, :, None]
+    cum = jnp.cumsum(dA.reshape(b, h, nc, chunk), axis=-1).reshape(b, h, l)
+    Bt = B.transpose(0, 2, 1, 3)                    # (b, g, l, n)
+    Ct = C.transpose(0, 2, 1, 3)
+
+    def head(bi, hi, ci):
+        return (bi, hi, ci, 0)
+
+    def row(bi, hi, ci):
+        return (bi, hi, 0, ci)
+
+    def grouped(bi, hi, ci, gg=group):
+        return (bi, hi // gg, ci, 0)
+
     kernel = functools.partial(_ssd_kernel, chunk=chunk)
-    return pl.pallas_call(
+    y = pl.pallas_call(
         kernel,
         grid=(b, h, nc),
         in_specs=[
-            pl.BlockSpec((1,), lambda bi, hi, ci: (hi,)),
-            pl.BlockSpec((1, chunk, 1, p), lambda bi, hi, ci: (bi, ci, hi, 0)),
-            pl.BlockSpec((1, chunk, 1), lambda bi, hi, ci: (bi, ci, hi)),
-            pl.BlockSpec(
-                (1, chunk, 1, n), lambda bi, hi, ci, gg=group: (bi, ci, hi // gg, 0)
-            ),
-            pl.BlockSpec(
-                (1, chunk, 1, n), lambda bi, hi, ci, gg=group: (bi, ci, hi // gg, 0)
-            ),
+            pl.BlockSpec((1, 1, chunk, p), head),
+            pl.BlockSpec((1, 1, chunk, 1), head),
+            pl.BlockSpec((1, 1, 1, chunk), row),
+            pl.BlockSpec((1, 1, chunk, 1), head),
+            pl.BlockSpec((1, 1, 1, chunk), row),
+            pl.BlockSpec((1, 1, chunk, n), grouped),
+            pl.BlockSpec((1, 1, chunk, n), grouped),
         ],
-        out_specs=pl.BlockSpec((1, chunk, 1, p), lambda bi, hi, ci: (bi, ci, hi, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, l, h, p), x.dtype),
+        out_specs=pl.BlockSpec((1, 1, chunk, p), head),
+        out_shape=jax.ShapeDtypeStruct((b, h, l, p), x.dtype),
         scratch_shapes=[pltpu.VMEM((p, n), jnp.float32)],
-        interpret=interpret,
-    )(A, x, dt, B, C)
+        interpret=checked_interpret(interpret),
+    )(
+        xt,
+        dtt[..., None], dtt[:, :, None, :],
+        cum[..., None], cum[:, :, None, :],
+        Bt, Ct,
+    )
+    return y.transpose(0, 2, 1, 3)

@@ -22,10 +22,10 @@ def _ssd(x, dt, A, B, C, chunk, impl):
 
 
 def _forward(x, dt, A, B, C, chunk, impl):
-    if impl == "pallas":
-        return _kernel.ssd_scan_fwd(x, dt, A, B, C, chunk=chunk, interpret=True)
-    if impl == "pallas_tpu":
-        return _kernel.ssd_scan_fwd(x, dt, A, B, C, chunk=chunk, interpret=False)
+    if impl in ("pallas", "pallas_tpu"):
+        return _kernel.ssd_scan_fwd(
+            x, dt, A, B, C, chunk=chunk, interpret=impl == "pallas"
+        )
     y, _ = _ref.ssd_chunked(x, dt, A, B, C, chunk=chunk)
     return y
 

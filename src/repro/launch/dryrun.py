@@ -1,10 +1,6 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# The two lines above MUST run before any other import (jax locks the device
-# count on first init).  This module is the multi-pod dry-run driver: it
-# lowers + compiles every (architecture x input-shape) cell on the production
-# mesh, prints memory_analysis()/cost_analysis(), and records the roofline
-# terms the perf loop consumes.
+# The multi-pod dry-run driver: it lowers + compiles every (architecture x
+# input-shape) cell on the production mesh, prints memory_analysis() /
+# cost_analysis(), and records the roofline terms the perf loop consumes.
 #
 # Usage:
 #   python -m repro.launch.dryrun --arch gemma2_9b --shape train_4k
@@ -20,6 +16,7 @@ import argparse
 import dataclasses
 import json
 import math
+import os
 import subprocess
 import sys
 import time
@@ -327,4 +324,7 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    # the production mesh is 512 virtual CPU devices; jax fixes the device
+    # count when its backend starts, which no import above does
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
     sys.exit(main())

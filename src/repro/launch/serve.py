@@ -77,6 +77,7 @@ def main(argv=None):
 
     from repro.configs import base
     from repro.core.session import default_session
+    from repro.launch import use_compile_cache
     from repro.launch.mesh import make_host_communicator
     from repro.runtime.server import (
         DisaggregatedServer,
@@ -85,6 +86,7 @@ def main(argv=None):
         ServerConfig,
     )
 
+    use_compile_cache()
     cfg = base.get_smoke_config(args.arch) if args.smoke else base.get_config(args.arch)
     pcfg = base.get_parallel(args.arch)
 
@@ -98,8 +100,11 @@ def main(argv=None):
         )
         from repro import tune as tune_mod
 
+        # uncalibrated: the plan depends on committed code only, never on
+        # dry-run artifacts a checkout may or may not hold
         result = tune_mod.tune(
             args.arch, shape, config=cfg, space=base.plan_space(args.arch),
+            calibrate=False,
         )
         plan = result.plan
         print(f"autotuned plan: {plan.slug()} "

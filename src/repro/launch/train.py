@@ -32,9 +32,11 @@ def resolve_plan(args, cfg, devices):
             shape = base.ShapeConfig(
                 f"train_{args.seq}", args.seq, args.batch, "train"
             )
+            # uncalibrated: the plan depends on committed code only, never
+            # on dry-run artifacts a checkout may or may not hold
             result = tune_mod.tune(
                 args.arch, shape, devices, config=cfg,
-                space=base.plan_space(args.arch),
+                space=base.plan_space(args.arch), calibrate=False,
             )
             logging.getLogger("repro.launch").info(
                 "autotuned plan: %s (predicted %.4fs over %d candidates)",
@@ -127,10 +129,12 @@ def main(argv=None):
     logging.basicConfig(level=logging.INFO, format="%(name)s %(message)s")
 
     from repro.configs import base
+    from repro.launch import use_compile_cache
     from repro.launch.mesh import make_host_communicator
     from repro.runtime.faults import FaultInjector
     from repro.runtime.trainer import Trainer, TrainerConfig
 
+    use_compile_cache()
     cfg = base.get_smoke_config(args.arch) if args.smoke else base.get_config(args.arch)
     pcfg = base.get_parallel(args.arch)
     if args.mesh == "auto":

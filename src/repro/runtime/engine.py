@@ -131,6 +131,9 @@ class Engine:
             "_engine_insert_reqs", {}
         )
         self._decode_req: PersistentRequest | None = None
+        # the last decode step's logits, one row per slot (None before the
+        # first step) — what a caller reads to check the cached decode path
+        self.logits: jax.Array | None = None
         self._rid = 0
         self._admit_seq = 0
         self._key0 = jax.random.PRNGKey(scfg.seed)   # argmax path ignores it
@@ -373,6 +376,7 @@ class Engine:
             logits, self.cache = self._decode_req(
                 self.server.params, self.cache, self.tok
             )
+            self.logits = logits
             key = (
                 jax.random.fold_in(self._key0, self._steps)
                 if self.scfg.temperature > 0 else self._key0

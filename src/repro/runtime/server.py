@@ -91,10 +91,13 @@ class Server:
         self.comm = comm if isinstance(comm, Communicator) else Communicator(comm)
         self.mesh = mesh = self.comm.mesh
         self.bundle = model_api.build(cfg)
+        # built under its target shardings: the whole tree never lands on
+        # one device first
+        key = jax.random.PRNGKey(scfg.seed)
+        shapes = jax.eval_shape(self.bundle.init, key)
+        pshard = rules.shardings(rules.param_specs(shapes, mesh, pcfg), mesh)
         with mesh:
-            self.params = jax.jit(self.bundle.init)(jax.random.PRNGKey(scfg.seed))
-            pspecs = rules.param_specs(self.params, mesh, pcfg)
-            self.params = jax.device_put(self.params, rules.shardings(pspecs, mesh))
+            self.params = jax.jit(self.bundle.init, out_shardings=pshard)(key)
         # persistent steps, keyed by argument signature (shape bucket): one
         # AOT compile per bucket, MPI_Start re-fires ever after
         self._prefill_reqs: dict[tuple, PersistentRequest] = {}

@@ -404,15 +404,16 @@ class Trainer:
     # -- assembly -------------------------------------------------------------
 
     def init_state(self):
+        # params and optimiser state are built under their declared
+        # shardings (the whole tree never lands on one device first); the
+        # persistent executable is bound to them (ERR_REQUEST on drift)
+        key = jax.random.PRNGKey(self.tcfg.seed)
+        p_shapes = jax.eval_shape(self.bundle.init, key)
+        o_shapes = jax.eval_shape(self.opt.init, p_shapes)
+        pshard, oshard = self._state_shardings(p_shapes, o_shapes)
         with self.mesh:
-            params = jax.jit(self.bundle.init)(jax.random.PRNGKey(self.tcfg.seed))
-            pspecs = self._param_pspecs(params)
-            params = jax.device_put(params, rules.shardings(pspecs, self.mesh))
-            opt_state = jax.jit(self.opt.init)(params)
-            # pin the optimiser state to its declared shardings up front: the
-            # persistent executable is bound to them (ERR_REQUEST on drift)
-            _, oshard = self._state_shardings(params, opt_state)
-            opt_state = jax.device_put(opt_state, oshard)
+            params = jax.jit(self.bundle.init, out_shardings=pshard)(key)
+            opt_state = jax.jit(self.opt.init, out_shardings=oshard)(params)
         return params, opt_state
 
     def _param_pspecs(self, params):

@@ -38,6 +38,7 @@ SHARDS = {
     "kernels": [
         "tests/test_kernels.py",
         "tests/test_ring_attention.py",
+        "tests/test_tpu_compile.py",
     ],
     "runtime": [
         "tests/test_checkpoint.py",
@@ -47,6 +48,7 @@ SHARDS = {
         "tests/test_elastic_multidevice.py",
         "tests/test_elastic_runtime.py",
         "tests/test_engine.py",
+        "tests/test_launch.py",
         "tests/test_models.py",
         "tests/test_server.py",
         "tests/test_trainer.py",

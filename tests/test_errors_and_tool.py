@@ -77,3 +77,12 @@ def test_hlo_collective_parse_smoke():
     )
     assert stats.count["all-gather"] == 1
     assert stats.result_bytes["all-gather"] == 16 * 32 * 4
+
+
+def test_chip_peaks_keyed_by_device_kind():
+    v5e = tool.chip_peaks("TPU v5 lite")
+    assert v5e.flops_bf16 == tool.PEAK_FLOPS_BF16 == 197e12
+    assert v5e.hbm_bandwidth == tool.HBM_BANDWIDTH
+    # a device with no published entry is an error, not the v5e default
+    with pytest.raises(errors.ArgError, match="cpu"):
+        tool.chip_peaks("cpu")

@@ -78,7 +78,9 @@ def test_flash_attention_ragged_lengths(sq, sk, causal):
     q = jax.random.normal(ks[0], (1, sq, 4, 16))
     k = jax.random.normal(ks[1], (1, sk, 2, 16))
     v = jax.random.normal(ks[2], (1, sk, 2, 16))
-    out = fk.flash_attention_fwd(q, k, v, causal=causal, block_q=128, block_k=128)
+    out = fk.flash_attention_fwd(
+        q, k, v, causal=causal, block_q=128, block_k=128, interpret=True
+    )
     ref = fa.flash_attention(q, k, v, causal=causal, impl="ref")
     assert out.shape == ref.shape
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5, rtol=2e-5)
@@ -99,7 +101,9 @@ def test_flash_attention_ragged_features(feature):
     kw = {"window": dict(sliding_window=100),
           "prefix": dict(prefix_len=40),
           "softcap": dict(logit_softcap=30.0)}[feature]
-    out = fk.flash_attention_fwd(q, k, v, causal=True, block_q=128, block_k=128, **kw)
+    out = fk.flash_attention_fwd(
+        q, k, v, causal=True, block_q=128, block_k=128, interpret=True, **kw
+    )
     ref = fa.flash_attention(q, k, v, causal=True, impl="ref", **kw)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5, rtol=2e-5)
 
@@ -186,3 +190,18 @@ def test_quant_pallas_matches_ref_exactly():
     assert p1 == p2
     np.testing.assert_array_equal(np.asarray(q1), np.asarray(q2))
     np.testing.assert_allclose(np.asarray(s1), np.asarray(s2), rtol=1e-6)
+
+
+def test_interpret_mode_is_refused_on_a_tpu_backend(monkeypatch):
+    """Interpret mode is a CPU-validation choice: on a TPU backend it would
+    stand in for the compiled kernel, so asking for it there is an error."""
+
+    from repro.core import errors
+    from repro.kernels import checked_interpret
+
+    assert checked_interpret(True) is True   # the CPU here
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert checked_interpret(False) is False
+    q, k, v = _qkv(jax.random.PRNGKey(0), 1, 128, 2, 2, 16, jnp.float32)
+    with pytest.raises(errors.UnsupportedError, match="pallas_tpu"):
+        fa.flash_attention(q, k, v, causal=True, impl="pallas")

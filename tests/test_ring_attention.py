@@ -38,7 +38,9 @@ def test_ring_step_kernel_matches_jnp_twin(causal, Hk):
         q_offset=jnp.int32(64), k_offset=jnp.int32(32), kv_len=jnp.int32(50),
         scale=0.25, causal=causal,
     )
-    out_k = rk.ring_step_fwd(q, k, v, m, l, acc, block_q=32, block_k=32, **kw)
+    out_k = rk.ring_step_fwd(
+        q, k, v, m, l, acc, block_q=32, block_k=32, interpret=True, **kw
+    )
     out_r = rk.ring_step_ref(q, k, v, m, l, acc, **kw)
     for a, b in zip(out_k, out_r):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-5, rtol=1e-5)
@@ -57,14 +59,18 @@ def test_ring_step_skips_fully_masked_tiles_consistently():
     # KV block strictly in the future of every Q row: carry must be unchanged
     kw = dict(q_offset=jnp.int32(0), k_offset=jnp.int32(512),
               kv_len=jnp.int32(64), scale=0.25, causal=True)
-    m2, l2, acc2 = rk.ring_step_fwd(q, k, v, m, l, acc, block_q=32, block_k=32, **kw)
+    m2, l2, acc2 = rk.ring_step_fwd(
+        q, k, v, m, l, acc, block_q=32, block_k=32, interpret=True, **kw
+    )
     np.testing.assert_array_equal(np.asarray(m2), np.asarray(m))
     np.testing.assert_array_equal(np.asarray(l2), np.asarray(l))
     np.testing.assert_array_equal(np.asarray(acc2), np.asarray(acc))
     # kv_len == 0 (a fully padded shard): same invariant, non-causal
     kw = dict(q_offset=jnp.int32(0), k_offset=jnp.int32(0),
               kv_len=jnp.int32(0), scale=0.25, causal=False)
-    m2, l2, acc2 = rk.ring_step_fwd(q, k, v, m, l, acc, block_q=32, block_k=32, **kw)
+    m2, l2, acc2 = rk.ring_step_fwd(
+        q, k, v, m, l, acc, block_q=32, block_k=32, interpret=True, **kw
+    )
     np.testing.assert_array_equal(np.asarray(l2), np.asarray(l))
 
 
