@@ -13,6 +13,10 @@ in a traced program.
   to ``pvar_info`` and drifts silently.  Dynamically-formatted names
   (f-strings in the facade binder) are covered at runtime by
   ``tool.pvar_strict`` instead.
+* :func:`unregistered_spans` — the same audit for span names: every literal
+  name passed to ``tool.span`` (under any name its module imports it as)
+  must be ``span_register``ed in ``tool.SPANS``, so that ``span_info``
+  lists every span a trace can hold.
 """
 
 from __future__ import annotations
@@ -90,8 +94,10 @@ def swallowed_failures(paths: Iterable[str | Path]) -> list[Finding]:
     return findings
 
 
-def _literal_pvar_writes(paths: Iterable[str | Path]) -> list[tuple[str, str]]:
-    """(pvar name, file:line) for every literal pvar_count/pvar_add call."""
+def _literal_names(paths: Iterable[str | Path], match) -> list[tuple[str, str]]:
+    """(name, file:line) for every call whose first argument is a string
+    literal and whose callee ``match(tree)`` accepts, ``tree`` being the
+    module the call is in."""
 
     writes: list[tuple[str, str]] = []
     for path in _py_files(paths):
@@ -99,13 +105,9 @@ def _literal_pvar_writes(paths: Iterable[str | Path]) -> list[tuple[str, str]]:
             tree = ast.parse(path.read_text(), filename=str(path))
         except SyntaxError:
             continue                      # reported by swallowed_failures
+        accepts = match(tree)
         for node in ast.walk(tree):
-            if not isinstance(node, ast.Call) or not node.args:
-                continue
-            fn = node.func
-            name = fn.attr if isinstance(fn, ast.Attribute) else (
-                fn.id if isinstance(fn, ast.Name) else "")
-            if name not in ("pvar_count", "pvar_add"):
+            if not isinstance(node, ast.Call) or not node.args or not accepts(node.func):
                 continue
             arg = node.args[0]
             if isinstance(arg, ast.Constant) and isinstance(arg.value, str):
@@ -113,9 +115,59 @@ def _literal_pvar_writes(paths: Iterable[str | Path]) -> list[tuple[str, str]]:
     return writes
 
 
-def unregistered_pvars(paths: Iterable[str | Path]) -> list[Finding]:
+def _pvar_writes(tree: ast.Module):
+    def accepts(fn: ast.expr) -> bool:
+        name = fn.attr if isinstance(fn, ast.Attribute) else (
+            fn.id if isinstance(fn, ast.Name) else "")
+        return name in ("pvar_count", "pvar_add")
+
+    return accepts
+
+
+def _dotted(node: ast.expr) -> str:
+    """``a.b.c`` for a chain of names and attributes, else ``""``."""
+
+    if isinstance(node, ast.Name):
+        return node.id
+    if isinstance(node, ast.Attribute):
+        head = _dotted(node.value)
+        return f"{head}.{node.attr}" if head else ""
+    return ""
+
+
+def _tool_spans(tree: ast.Module):
+    """Calls of ``repro.core.tool.span`` under whatever name the module
+    binds it to: ``tool.span`` (``from repro.core import tool [as t]``,
+    ``import repro.core.tool [as t]``) or a bare ``span`` (``from
+    repro.core.tool import span [as s]``)."""
+
+    modules, funcs = {"tool"}, set()
+    for node in ast.walk(tree):
+        if isinstance(node, ast.ImportFrom):
+            where = "." * node.level + (node.module or "")
+            for a in node.names:
+                if a.name == "tool" and where in ("repro.core", "."):
+                    modules.add(a.asname or a.name)
+                elif a.name == "span" and where in ("repro.core.tool", ".tool"):
+                    funcs.add(a.asname or a.name)
+        elif isinstance(node, ast.Import):
+            for a in node.names:
+                if a.name == "repro.core.tool":
+                    modules.add(a.asname or a.name)
+
+    def accepts(fn: ast.expr) -> bool:
+        if isinstance(fn, ast.Name):
+            return fn.id in funcs
+        return (isinstance(fn, ast.Attribute) and fn.attr == "span"
+                and _dotted(fn.value) in modules)
+
+    return accepts
+
+
+def _import_registries() -> None:
     # importing the runtime/checkpoint layers runs their module-level
-    # pvar_register calls, populating the registry the audit compares against
+    # pvar_register/span_register calls, populating the registries the
+    # audits compare against
     import repro.checkpoint.manager   # noqa: F401
     import repro.core                 # noqa: F401
     import repro.runtime.engine       # noqa: F401
@@ -123,10 +175,14 @@ def unregistered_pvars(paths: Iterable[str | Path]) -> list[Finding]:
     import repro.runtime.server       # noqa: F401
     import repro.runtime.trainer      # noqa: F401
     import repro.tune                 # noqa: F401
+
+
+def unregistered_pvars(paths: Iterable[str | Path]) -> list[Finding]:
+    _import_registries()
     from repro.core import tool
 
     findings: list[Finding] = []
-    for name, where in _literal_pvar_writes(paths):
+    for name, where in _literal_names(paths, _pvar_writes):
         if name not in tool.PVARS:
             findings.append(Finding(
                 ErrorClass.ERR_ARG, "unregistered-pvar",
@@ -137,6 +193,21 @@ def unregistered_pvars(paths: Iterable[str | Path]) -> list[Finding]:
     return findings
 
 
+def unregistered_spans(paths: Iterable[str | Path]) -> list[Finding]:
+    _import_registries()
+    from repro.core import tool
+
+    return [
+        Finding(
+            ErrorClass.ERR_ARG, "unregistered-span",
+            f"span {name!r} is opened but never span_register()ed — "
+            f"span_info would not list it", where,
+        )
+        for name, where in _literal_names(paths, _tool_spans)
+        if name not in tool.SPANS
+    ]
+
+
 def run_static(paths: Iterable[str | Path]) -> list[Finding]:
     paths = list(paths)
-    return swallowed_failures(paths) + unregistered_pvars(paths)
+    return swallowed_failures(paths) + unregistered_pvars(paths) + unregistered_spans(paths)

@@ -431,6 +431,8 @@ class PersistentRequest:
         from repro.core import tool
 
         tool.pvar_count("persistent_init")
+        #: the jitted function's name: the ``name`` stat of its dispatches
+        self.name = getattr(jitted, "__name__", type(jitted).__name__)
         self._lowered = jitted.lower(*example_args, **(example_kwargs or {}))
         self._compiled = self._lowered.compile()
         self.donate_argnums = tuple(donate_argnums)
@@ -509,18 +511,19 @@ class PersistentRequest:
 
         from repro.core import tool
 
-        try:
-            out = self._compiled(*args)
-        except (TypeError, ValueError):
-            # the compiled executable rejects drifted argument lists with
-            # TypeError (shape/dtype/pytree mismatch) or ValueError
-            # (sharding mismatch) — the expected failures; anything else
-            # propagates untouched
-            if errors.error_checking_enabled():
-                self._validate(args)     # raises ERR_REQUEST if args drifted
-            raise
-        # only successful dispatches count as MPI_Start events
-        tool.pvar_count("persistent_start")
+        with tool.span("repro.request.start", name=self.name):
+            try:
+                out = self._compiled(*args)
+            except (TypeError, ValueError):
+                # the compiled executable rejects drifted argument lists
+                # with TypeError (shape/dtype/pytree mismatch) or ValueError
+                # (sharding mismatch) — the expected failures; anything else
+                # propagates untouched
+                if errors.error_checking_enabled():
+                    self._validate(args)     # raises ERR_REQUEST if args drifted
+                raise
+            # only successful dispatches count as MPI_Start events
+            tool.pvar_count("persistent_start")
         self._started += 1
         return out
 

@@ -12,6 +12,9 @@ MPI_T exposes *performance variables* (pvars) and *control variables*
   stringly-typed control variables.
 * call-site counters (``pvar_counters``) count issued operations per kind,
   maintained by the interface layer.
+* **spans** are the events analogue: :func:`span` marks where the host
+  spends its time (a persistent dispatch, an engine or trainer phase) in
+  the profiler's own trace, on the clock of the device's operations.
 
 Hardware model constants for the roofline (TPU v5e) also live here so every
 consumer agrees on them.
@@ -24,6 +27,8 @@ import re
 import threading
 from collections import defaultdict
 from typing import Any, Callable
+
+from jax.profiler import TraceAnnotation
 
 from repro.analysis import events as analysis_events
 from repro.core import errors
@@ -498,3 +503,39 @@ pvar_register("ckpt_save", "checkpoint saves issued (async or sync)")
 pvar_register("ckpt_save_failed", "checkpoint saves that surfaced an I/O error")
 pvar_register("ckpt_restore", "checkpoint restores")
 pvar_register("ckpt_wait", "checkpoint completions joined (wait)")
+
+
+# --------------------------------------------------------------------------
+# spans (the MPI_T events analogue)
+# --------------------------------------------------------------------------
+
+#: Documented spans, ``name -> doc`` (the :data:`PVARS` of events).
+SPANS: dict[str, str] = {}
+
+
+def span_register(name: str, doc: str) -> None:
+    """Describe a span (idempotent); the static audit holds every literal
+    :func:`span` name to this registry, as it holds pvars to theirs."""
+
+    SPANS.setdefault(name, doc)
+
+
+def span_info() -> dict[str, str]:
+    return dict(SPANS)
+
+
+def span(name: str, /, **stats: int | str):
+    """A host span in the profiler's trace: a context manager over
+    ``jax.profiler.TraceAnnotation``, so it lands on the host plane of the
+    ``.xplane.pb`` beside the device's operations.  ``stats`` (small ints,
+    or a short name) become the event's stats; what is known only at the
+    end goes in with ``set_metadata`` on the entered span.  A span's parent
+    is the enclosing span of the same thread.  With no profiler session it
+    records nothing."""
+
+    return TraceAnnotation(name, **stats)
+
+
+span_register("repro.request.start",
+              "one persistent dispatch (MPI_Start): the compiled executable's "
+              "call and its pvar; stat name = the jitted function")

@@ -35,7 +35,6 @@ from __future__ import annotations
 
 import collections
 import dataclasses
-import time
 
 import jax
 import jax.numpy as jnp
@@ -50,6 +49,26 @@ tool.pvar_register("engine:admit", "requests admitted into a running decode batc
 tool.pvar_register("engine:retire", "requests retired from the continuous batch")
 tool.pvar_register("engine:preempt", "requests preempted under block-pool pressure")
 tool.pvar_register("trace:insert_row", "decode-row insert kernels traced (want 1 per shape)")
+tool.span_register("repro.engine.step",
+                   "one Engine.step call; stats step, running, waiting (at entry)")
+tool.span_register("repro.engine.admit",
+                   "one admission side batch: prefill, first-token sample, inserts; "
+                   "stats rows, padded_rows, length, real_tokens")
+tool.span_register("repro.engine.admit_row",
+                   "one admitted request placed in its slot (or finished at once); "
+                   "stats rid, slot")
+tool.span_register("repro.engine.first_token",
+                   "the host's wait for a side batch's sampled first tokens; stat rows")
+tool.span_register("repro.engine.grow",
+                   "block growth before the decode step; stat preempted")
+tool.span_register("repro.engine.sample",
+                   "the fold_in and sample dispatches after the decode step")
+tool.span_register("repro.engine.wait",
+                   "the host's wait for the decode step's sampled tokens")
+tool.span_register("repro.engine.retire",
+                   "append each row's token, retire finished rows; stat retired")
+tool.span_register("repro.engine.finish",
+                   "one request retired; stats rid, tokens")
 
 
 @dataclasses.dataclass
@@ -82,9 +101,6 @@ class ServingRequest:
                                        # are picked newest-first)
     preemptions: int = 0
     block_ids: list = dataclasses.field(default_factory=list)
-    arrival_s: float = 0.0
-    first_token_s: float | None = None
-    finish_s: float | None = None
 
 
 class Engine:
@@ -184,10 +200,7 @@ class Engine:
             errors.ErrorClass.ERR_ARG,
             f"max_new={budget} outside [1, {self.scfg.max_new_tokens}]",
         )
-        r = ServingRequest(
-            tokens=tokens, max_new=budget, rid=self._rid,
-            arrival_s=time.perf_counter(),
-        )
+        r = ServingRequest(tokens=tokens, max_new=budget, rid=self._rid)
         self._rid += 1
         self.waiting.append(r)
         return r
@@ -236,7 +249,7 @@ class Engine:
             self._insert_reqs[key] = req
         return req
 
-    def _admit(self, now: float) -> None:
+    def _admit(self) -> None:
         free = [s for s in range(self.num_slots) if self.active[s] is None]
         admitted: list[tuple[ServingRequest, int, int]] = []
         while free and self.waiting:
@@ -261,25 +274,33 @@ class Engine:
             by_len.setdefault(plen, []).append((r, slot))
         for plen, group in sorted(by_len.items()):
             nrows = min(self.num_slots, 1 << (len(group) - 1).bit_length())
-            toks = np.zeros((nrows, plen), np.int32)
-            for row, (r, _slot) in enumerate(group):
-                toks[row] = self._padded_content(r)
-            batch = {"tokens": jnp.asarray(toks)}
-            extra = self.capacity - plen
-            with self.server.mesh:
-                logits, pcache = self.server._prefill_request(
-                    batch, extra_capacity=extra
-                )(self.server.params, batch)
-                first = self.server._sample(logits, self.server._next_key())
-                insert = self._insert_request(pcache)
+            real = sum(len(r.tokens) + max(0, len(r.generated) - 1) for r, _ in group)
+            with tool.span("repro.engine.admit", rows=len(group), padded_rows=nrows,
+                           length=plen, real_tokens=real):
+                self._admit_batch(plen, nrows, group)
+
+    def _admit_batch(self, plen: int, nrows: int,
+                     group: list[tuple[ServingRequest, int]]) -> None:
+        toks = np.zeros((nrows, plen), np.int32)
+        for row, (r, _slot) in enumerate(group):
+            toks[row] = self._padded_content(r)
+        batch = {"tokens": jnp.asarray(toks)}
+        extra = self.capacity - plen
+        with self.server.mesh:
+            logits, pcache = self.server._prefill_request(
+                batch, extra_capacity=extra
+            )(self.server.params, batch)
+            first = self.server._sample(logits, self.server._next_key())
+            insert = self._insert_request(pcache)
+            with tool.span("repro.engine.first_token", rows=len(group)):
                 first_host = np.asarray(first)
-                for row, (r, slot) in enumerate(group):
+            for row, (r, slot) in enumerate(group):
+                with tool.span("repro.engine.admit_row", rid=r.rid, slot=slot):
                     if r.generated:
                         t = int(r.generated[-1])   # resumed: pending token
                     else:
                         t = int(first_host[row])   # fresh: sample prefill logits
                         r.generated.append(t)
-                        r.first_token_s = now
                         self._generated_total += 1
                         stopped = (
                             self.scfg.stop_token is not None
@@ -287,10 +308,12 @@ class Engine:
                         )
                         if stopped or r.max_new <= 1:
                             # done before ever occupying a decode slot
-                            self.pool.release(slot)
-                            r.state, r.finish_s = FINISHED, time.perf_counter()
-                            self.finished.append(r)
-                            tool.pvar_count("engine:retire")
+                            with tool.span("repro.engine.finish", rid=r.rid,
+                                           tokens=len(r.generated)):
+                                self.pool.release(slot)
+                                r.state = FINISHED
+                                self.finished.append(r)
+                                tool.pvar_count("engine:retire")
                             continue
                     self.cache, self.tok = insert(
                         self.cache, self.tok, pcache,
@@ -361,33 +384,49 @@ class Engine:
         fire the persistent decode step, append/retire.  Returns the
         requests that finished this step."""
 
-        now = time.perf_counter()
-        self._admit(now)
-        self._grow_or_preempt()
-        if not any(r is not None for r in self.active):
-            return []
+        with tool.span("repro.engine.step", step=self._steps,
+                       running=self.num_slots - self.active.count(None),
+                       waiting=len(self.waiting)):
+            self._admit()
+            with tool.span("repro.engine.grow") as sp:
+                before = self._preempt_count
+                self._grow_or_preempt()
+                sp.set_metadata(preempted=self._preempt_count - before)
+            if not any(r is not None for r in self.active):
+                return []
 
-        with self.server.mesh:
-            # the slot table's signature never changes, so the persistent
-            # request is resolved once and re-fired ever after (the per-step
-            # signature hash would otherwise be the scheduler's biggest tax)
-            if self._decode_req is None:
-                self._decode_req = self.server._decode_request(self.cache, self.tok)
-            logits, self.cache = self._decode_req(
-                self.server.params, self.cache, self.tok
-            )
-            self.logits = logits
-            key = (
-                jax.random.fold_in(self._key0, self._steps)
-                if self.scfg.temperature > 0 else self._key0
-            )
-            tok = self.server._sample(logits, key)
-            self.tok = tok[:, None]
-        tok_host = np.asarray(tok)
-        self._steps += 1
+            with self.server.mesh:
+                # the slot table's signature never changes, so the persistent
+                # request is resolved once and re-fired ever after (the
+                # per-step signature hash would otherwise be the scheduler's
+                # biggest tax)
+                if self._decode_req is None:
+                    self._decode_req = self.server._decode_request(self.cache, self.tok)
+                logits, self.cache = self._decode_req(
+                    self.server.params, self.cache, self.tok
+                )
+                self.logits = logits
+                with tool.span("repro.engine.sample"):
+                    key = (
+                        jax.random.fold_in(self._key0, self._steps)
+                        if self.scfg.temperature > 0 else self._key0
+                    )
+                    tok = self.server._sample(logits, key)
+                    self.tok = tok[:, None]
+            with tool.span("repro.engine.wait"):
+                tok_host = np.asarray(tok)
+            self._steps += 1
+
+            with tool.span("repro.engine.retire") as sp:
+                done = self._retire(tok_host)
+                sp.set_metadata(retired=len(done))
+            return done
+
+    def _retire(self, tok_host: np.ndarray) -> list[ServingRequest]:
+        """Append each running row's sampled token; retire the rows that
+        stopped or spent their budget."""
 
         done: list[ServingRequest] = []
-        now = time.perf_counter()
         for s in range(self.num_slots):
             r = self.active[s]
             if r is None:
@@ -398,13 +437,13 @@ class Engine:
             self._generated_total += 1
             stopped = self.scfg.stop_token is not None and t == self.scfg.stop_token
             if stopped or len(r.generated) >= r.max_new:
-                self.pool.release(s)
-                r.state, r.slot = FINISHED, None
-                r.finish_s = now
-                self.active[s] = None
-                self.finished.append(r)
-                done.append(r)
-                tool.pvar_count("engine:retire")
+                with tool.span("repro.engine.finish", rid=r.rid, tokens=len(r.generated)):
+                    self.pool.release(s)
+                    r.state, r.slot = FINISHED, None
+                    self.active[s] = None
+                    self.finished.append(r)
+                    done.append(r)
+                    tool.pvar_count("engine:retire")
         return done
 
     def run(self) -> list[ServingRequest]:

@@ -216,9 +216,10 @@ class Server:
         key = self._next_key()
         with self.mesh:
             logits, cache = self._prefill_request(batch)(self.params, batch)
+            tok = self._sample(logits, key)
+            jax.block_until_ready(tok)
             t_prefill = time.perf_counter() - t0
 
-            tok = self._sample(logits, key)
             t1 = time.perf_counter()
             outs = self._decode_loop(cache, tok, key)
             t_decode = time.perf_counter() - t1

@@ -93,6 +93,11 @@ tool.pvar_register(
     "TrainerConfig layouts built through the deprecated "
     "pipeline_stages/ring_attention int knobs instead of a ParallelPlan",
 )
+tool.span_register("repro.trainer.step", "one step of the trainer's loop; stat step")
+tool.span_register("repro.trainer.batch", "the step's batch: device_batch and device_put")
+tool.span_register("repro.trainer.wait", "the host's wait for the step's loss")
+tool.span_register("repro.trainer.record",
+                   "the log_every bookkeeping: loss and norm to host, pvar_read, log")
 
 _deprecated_knob_warned = False
 
@@ -605,54 +610,58 @@ class Trainer:
         # persistent engine take the failure path (checkpoint restore)
         retry_safe = not (self.tcfg.persistent and self.tcfg.donate)
         while step < steps:
-            if self.injector is not None:
-                joiners = self.injector.take_admissions(step)
-                if joiners:
-                    params, opt_state = self._grow(joiners, params, opt_state)
-            step_fn = self._compiled
-            batch = self.pipeline.device_batch(step, self.mesh, self.pcfg)
-            if self.tcfg.persistent:
-                # no-op when device_batch already matches the bound sharding
-                batch = jax.device_put(batch, self._bshard)
+            with tool.span("repro.trainer.step", step=step):
+                if self.injector is not None:
+                    joiners = self.injector.take_admissions(step)
+                    if joiners:
+                        params, opt_state = self._grow(joiners, params, opt_state)
+                step_fn = self._compiled
+                with tool.span("repro.trainer.batch"):
+                    batch = self.pipeline.device_batch(step, self.mesh, self.pcfg)
+                    if self.tcfg.persistent:
+                        # no-op when device_batch already matches the bound sharding
+                        batch = jax.device_put(batch, self._bshard)
 
-            def do_step():
-                new_p, new_o, metrics = step_fn(params, opt_state, batch)
-                jax.block_until_ready(metrics["loss"])
-                return new_p, new_o, metrics
+                def do_step():
+                    new_p, new_o, metrics = step_fn(params, opt_state, batch)
+                    with tool.span("repro.trainer.wait"):
+                        jax.block_until_ready(metrics["loss"])
+                    return new_p, new_o, metrics
 
-            (params, opt_state, metrics), info = self.guard.run(
-                step,
-                do_step,
-                retry_safe=retry_safe,
-                # a step sharing the host with an in-flight checkpoint save
-                # is slow from known interference, not worker sickness
-                exempt=self.ckpt is not None and self.ckpt.pending(),
-            )
-            step += 1
-            if step % self.tcfg.log_every == 0 or step == steps:
-                pvars = tool.pvar_read()
-                rec = {
-                    "step": step,
-                    "loss": float(metrics["loss"]),
-                    "grad_norm": float(metrics["grad_norm"]),
-                    **{k: float(v) for k, v in info.items() if k != "straggled"},
-                    "persistent_start": pvars.get("persistent_start", 0),
-                    "partition_ready": pvars.get("partition_ready", 0),
-                }
-                self.metrics_history.append(rec)
-                log.info(
-                    "step %(step)d loss %(loss).4f "
-                    "persistent_start %(persistent_start)d "
-                    "partition_ready %(partition_ready)d", rec,
+                (params, opt_state, metrics), info = self.guard.run(
+                    step,
+                    do_step,
+                    retry_safe=retry_safe,
+                    # a step sharing the host with an in-flight checkpoint save
+                    # is slow from known interference, not worker sickness
+                    exempt=self.ckpt is not None and self.ckpt.pending(),
                 )
-            if (
-                self.ckpt is not None
-                and self.tcfg.checkpoint_every
-                and step % self.tcfg.checkpoint_every == 0
-            ):
-                # the save's file I/O overlaps the following steps; the next
-                # save (or run-end/exit) joins it and surfaces any failure
-                self._checkpoint(step, params, opt_state)
+                step += 1
+                if step % self.tcfg.log_every == 0 or step == steps:
+                    with tool.span("repro.trainer.record"):
+                        pvars = tool.pvar_read()
+                        rec = {
+                            "step": step,
+                            "loss": float(metrics["loss"]),
+                            "grad_norm": float(metrics["grad_norm"]),
+                            **{k: float(v) for k, v in info.items() if k != "straggled"},
+                            "persistent_start": pvars.get("persistent_start", 0),
+                            "partition_ready": pvars.get("partition_ready", 0),
+                        }
+                        self.metrics_history.append(rec)
+                        log.info(
+                            "step %(step)d loss %(loss).4f "
+                            "persistent_start %(persistent_start)d "
+                            "partition_ready %(partition_ready)d", rec,
+                        )
+                if (
+                    self.ckpt is not None
+                    and self.tcfg.checkpoint_every
+                    and step % self.tcfg.checkpoint_every == 0
+                ):
+                    # the save's file I/O overlaps the following steps; the next
+                    # save (or run-end/exit) joins it and surfaces any failure
+                    self._checkpoint(step, params, opt_state)
         return params, opt_state, step
 
     # -- recovery ---------------------------------------------------------------

@@ -385,6 +385,53 @@ def test_no_swallowed_failures_repo_wide():
     assert f == [], [str(x) for x in f]
 
 
+def test_every_opened_span_is_registered():
+    f = static.unregistered_spans(["src", "benchmarks"])
+    assert f == [], [str(x) for x in f]
+
+
+def test_static_scan_flags_an_unregistered_span(tmp_path):
+    bad = tmp_path / "spans.py"
+    bad.write_text(
+        "from repro.core import tool\n"
+        "with tool.span('repro.engine.step', step=0):\n"
+        "    with tool.span('repro.never.registered_xyz'):\n"
+        "        pass\n"
+    )
+    f = static.run_static([str(tmp_path)])
+    spans = [x for x in f if x.check == "unregistered-span"]
+    assert [x.code for x in spans] == [ErrorClass.ERR_ARG]
+    assert "repro.never.registered_xyz" in spans[0].message
+    assert spans[0].subject.endswith("spans.py:3")
+
+
+@pytest.mark.parametrize("imported, call", [
+    ("from repro.core.tool import span", "span({!r})"),
+    ("from repro.core.tool import span as sp", "sp({!r})"),
+    ("from repro.core import tool as t", "t.span({!r})"),
+    ("import repro.core.tool", "repro.core.tool.span({!r})"),
+    ("import repro.core.tool as tl", "with tl.span({!r}, rid=1):\n    pass"),
+])
+def test_static_scan_follows_the_names_span_is_imported_as(tmp_path, imported, call):
+    (tmp_path / "spans.py").write_text("\n".join(
+        [imported, call.format("repro.never.registered_xyz"),
+         call.format("repro.engine.step"), ""]))
+    f = static.unregistered_spans([str(tmp_path)])
+    assert [x.check for x in f] == ["unregistered-span"]
+    assert "repro.never.registered_xyz" in f[0].message
+    assert f[0].subject.endswith("spans.py:2")
+
+
+def test_static_scan_ignores_other_functions_named_span(tmp_path):
+    (tmp_path / "other.py").write_text(
+        "from harness import span\n"
+        "import mylib\n"
+        "span('bench.window', True)\n"
+        "mylib.span('anything')\n"
+    )
+    assert static.unregistered_spans([str(tmp_path)]) == []
+
+
 def test_pvar_strict_rejects_unregistered():
     prev = tool.pvar_strict(True)
     try:
