@@ -359,24 +359,52 @@ def attention_decode(
         )
     else:
         kc, vc = cache_layer_read(k_layer, v_layer, k_scale_l, v_scale_l, dtype)
-        y = _decode_attend(q, kc, vc, valid, cfg)
+        y = _decode_attend(q, kc, vc, valid, cfg).astype(dtype)
     y = jnp.einsum("bshk,hkd->bsd", y, p["wo"])
     return y, (k_layer, v_layer, k_scale_l, v_scale_l)
 
 
-def _decode_attend(q, kc, vc, valid, cfg):
-    h, hk = q.shape[2], kc.shape[2]
-    if hk != h:
-        kc = jnp.repeat(kc, h // hk, axis=2)
-        vc = jnp.repeat(vc, h // hk, axis=2)
-    s = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32), kc.astype(jnp.float32))
+def _grouped_scores(q, kc, valid, cfg):
+    """Masked fp32 scores of decode queries ``q`` (B, T, H, Dh) against a
+    cached layer as stored, ``kc`` (B, S, Hk, Dh): (B, Hk, G, T, S) with
+    G = H // Hk.  Query head h reads KV head h // G (``jnp.repeat``'s
+    order), so the cache is never repeated to the query heads nor copied to
+    fp32: it enters the contraction as stored, accumulated in fp32.
+    HIGHEST keeps an MXU lowering of the grouped shape exact in fp32."""
+
+    b, t, h, dh = q.shape
+    hk = kc.shape[2]
+    qg = q.reshape(b, t, hk, h // hk, dh)
+    s = jnp.einsum(
+        "bqhgd,bkhd->bhgqk", qg, kc,
+        preferred_element_type=jnp.float32, precision=jax.lax.Precision.HIGHEST,
+    )
     s = s * _scale(cfg)
     s = common.softcap(s, cfg.attn_logit_softcap)
-    # valid is (capacity,) shared across the batch, or (B, capacity) per-row
-    mask = valid[None, None, None, :] if valid.ndim == 1 else valid[:, None, None, :]
-    s = jnp.where(mask, s, fa_ref.NEG_INF)
-    pattn = jax.nn.softmax(s, axis=-1)
-    return jnp.einsum("bhqk,bkhd->bqhd", pattn, vc.astype(jnp.float32)).astype(q.dtype)
+    # valid is (S,) shared across the batch, or (B, S) per-row
+    mask = valid if valid.ndim == 1 else valid[:, None, None, None, :]
+    return jnp.where(mask, s, fa_ref.NEG_INF)
+
+
+def _grouped_values(pattn, vc):
+    """fp32 ``pattn`` (B, Hk, G, T, S) against the cached ``vc``
+    (B, S, Hk, Dh) as stored → fp32 (B, T, H, Dh).  On the TPU the einsum's
+    promotion of ``vc`` to fp32 fuses into the contraction: no fp32 copy."""
+
+    b, hk, g, t, _ = pattn.shape
+    o = jnp.einsum(
+        "bhgqk,bkhd->bqhgd", pattn, vc,
+        preferred_element_type=jnp.float32, precision=jax.lax.Precision.HIGHEST,
+    )
+    return o.reshape(b, t, hk * g, vc.shape[-1])
+
+
+def _decode_attend(q, kc, vc, valid, cfg):
+    """Decode attention of ``q`` (B, T, H, Dh) over a cached layer; fp32
+    (B, T, H, Dh), cast by the caller."""
+
+    pattn = jax.nn.softmax(_grouped_scores(q, kc, valid, cfg), axis=-1)
+    return _grouped_values(pattn, vc)
 
 
 def _flash_decode_sharded(q, k_layer, v_layer, k_scale_l, v_scale_l, valid, cfg, pcfg,
@@ -397,22 +425,14 @@ def _flash_decode_sharded(q, k_layer, v_layer, k_scale_l, v_scale_l, valid, cfg,
 
     def body(ql, kl, vl, ksl, vsl, validl):
         kc, vc = cache_layer_read(kl, vl, ksl, vsl, dtype)
-        h, hk = ql.shape[2], kc.shape[2]
-        if hk != h:
-            kc = jnp.repeat(kc, h // hk, axis=2)
-            vc = jnp.repeat(vc, h // hk, axis=2)
-        s = jnp.einsum("bqhd,bkhd->bhqk", ql.astype(jnp.float32), kc.astype(jnp.float32))
-        s = s * _scale(cfg)
-        s = common.softcap(s, cfg.attn_logit_softcap)
-        maskl = (
-            validl[None, None, None, :] if validl.ndim == 1
-            else validl[:, None, None, :]
-        )
-        s = jnp.where(maskl, s, fa_ref.NEG_INF)
+        s = _grouped_scores(ql, kc, validl, cfg)
         m = jnp.max(s, axis=-1)
         p_ = jnp.exp(s - m[..., None])
         l = jnp.sum(p_, axis=-1)
-        o = jnp.einsum("bhqk,bkhd->bqhd", p_, vc.astype(jnp.float32))
+        o = _grouped_values(p_, vc)
+        # (B, Hk, G, T) → (B, H, T), the head order of ``o``
+        b, hk, g, t = m.shape
+        m, l = m.reshape(b, hk * g, t), l.reshape(b, hk * g, t)
         o = o / jnp.maximum(jnp.swapaxes(l, 1, 2), 1e-30)[..., None]
         return overlap.merge_partial_attention(o, m, l, comm).astype(ql.dtype)
 

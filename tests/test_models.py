@@ -135,3 +135,99 @@ def test_param_count_analytic_close_to_actual():
         actual = sum(int(np.prod(np.shape(p))) for p in jax.tree.leaves(params))
         analytic = cfg.param_count()
         assert abs(actual - analytic) / actual < 0.12, (arch, actual, analytic)
+
+
+# -- decode attention grouped by KV head ---------------------------------------------
+
+
+def _repeated_attend(q, kc, vc, valid, cfg):
+    """The GQA decode formula with the cache repeated to the query heads and
+    upcast whole, contracted in fp32: fp32 out, before any cast."""
+
+    from repro.kernels.flash_attention import ref as fa_ref
+    from repro.models import attention, common
+
+    h, hk = q.shape[2], kc.shape[2]
+    kc = jnp.repeat(kc, h // hk, axis=2).astype(jnp.float32)
+    vc = jnp.repeat(vc, h // hk, axis=2).astype(jnp.float32)
+    s = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32), kc) * attention._scale(cfg)
+    s = common.softcap(s, cfg.attn_logit_softcap)
+    mask = valid[None, None, None, :] if valid.ndim == 1 else valid[:, None, None, :]
+    s = jnp.where(mask, s, fa_ref.NEG_INF)
+    return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, axis=-1), vc)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("softcap", [None, 30.0])
+@pytest.mark.parametrize("mask", ["shared", "per_row"])
+@pytest.mark.parametrize("heads", [(24, 8), (8, 8), (8, 1)], ids=["gqa", "mha", "mqa"])
+def test_grouped_decode_attend_matches_repeat(heads, mask, softcap, dtype):
+    """Grouping the query heads by KV head is the repeated-cache formula in
+    fp32, for GQA, MHA and MQA, shared and per-row masks (rows of length 1
+    among them), with and without softcap, over fp32 and bf16 caches."""
+
+    import types
+
+    from repro.models import attention
+
+    h, hk = heads
+    b, s, dh = 4, 24, 16
+    ks = jax.random.split(jax.random.PRNGKey(3), 3)
+    # scores of tens, so that a softcap of 30 bends them
+    q = (20.0 * jax.random.normal(ks[0], (b, 1, h, dh))).astype(dtype)
+    kc = jax.random.normal(ks[1], (b, s, hk, dh)).astype(dtype)
+    vc = jax.random.normal(ks[2], (b, s, hk, dh)).astype(dtype)
+    if mask == "shared":
+        valid = jnp.arange(s) <= 9
+    else:
+        valid = jnp.arange(s)[None, :] < jnp.array([1, s, 7, 1])[:, None]
+    cfg = types.SimpleNamespace(query_scale=None, head_dim=dh, attn_logit_softcap=softcap)
+
+    got = attention._decode_attend(q, kc, vc, valid, cfg)
+    want = _repeated_attend(q, kc, vc, valid, cfg)
+    assert got.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-5)
+
+
+def _eqn_sizes(jaxpr):
+    """Element counts of every equation's outputs, nested jaxprs included."""
+
+    for eqn in jaxpr.eqns:
+        for v in eqn.outvars:
+            yield int(np.prod(v.aval.shape)), str(eqn.primitive)
+        for p in eqn.params.values():
+            for sub in p if isinstance(p, (tuple, list)) else (p,):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    yield from _eqn_sizes(inner)
+
+
+@pytest.mark.parametrize("per_row", [False, True], ids=["shared_pos", "per_row_pos"])
+def test_decode_attention_never_repeats_the_cache(per_row):
+    """One GQA decode layer (6 query heads over 2 KV heads), traced: no
+    equation outputs an array as large as the cache repeated to the query
+    heads, B·S·H·Dh elements (three times the cache)."""
+
+    import dataclasses
+
+    from repro.models import attention
+
+    cfg = dataclasses.replace(
+        base.get_smoke_config("phi4_mini_3_8b"), num_heads=6, num_kv_heads=2
+    )
+    pcfg = base.get_parallel("phi4_mini_3_8b")
+    b, s, h, hk, dh = 2, 64, cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+    p = attention.init_attention(jax.random.PRNGKey(0), cfg, jnp.bfloat16)
+    x1 = jnp.zeros((b, 1, cfg.d_model), jnp.bfloat16)
+    k_layer = jnp.zeros((b, s, hk, dh), jnp.bfloat16)
+    pos = jnp.array([5, 40], jnp.int32) if per_row else jnp.int32(5)
+
+    def layer(p, x1, k_layer, v_layer, pos):
+        return attention.attention_decode(
+            p, x1, k_layer, v_layer, None, None, pos, cfg, pcfg, sliding_window=None
+        )
+
+    jaxpr = jax.make_jaxpr(layer)(p, x1, k_layer, k_layer, pos)
+    repeated = b * s * h * dh
+    big = [(n, prim) for n, prim in _eqn_sizes(jaxpr.jaxpr) if n >= repeated]
+    assert not big, f"outputs of {repeated}+ elements: {big}"
