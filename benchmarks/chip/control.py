@@ -38,8 +38,8 @@ def serve_seed(spec, seed, seconds, control):
     import serve_driver
 
     res = serve_driver.run(spec, seed, seconds, False, time.perf_counter())
-    out = serve_driver.reference_numbers(spec["config"], spec["traffic"], seed,
-                                         res["picked"], res["last_step"], control)
+    out = serve_driver.reference_numbers(spec["family"], spec["config"], spec["traffic"],
+                                         seed, res["picked"], res["last_step"], control)
     return {**out, "in_window": res["in_window"]}
 
 
@@ -47,15 +47,15 @@ def train_seed(spec, seed, control):
     import output_check
     import train_driver
 
-    c, job = spec["config"], spec["traffic"]
+    c, job, fam = spec["config"], spec["traffic"], spec["family"]
     res = train_driver.run(spec, seed, 0.0, False, time.perf_counter())
-    ref = train_driver.reference(c, job, seed)
+    ref = train_driver.reference(fam, c, job, seed)
     out = {k: v for k, v in output_check.train_numbers(res["check"], ref).items()}
     out["losses"] = res["check"]["losses"]
     out["ref_losses"] = ref["losses"]
     if control:
         for name, kw in (("control", {"fp8": True}), ("half_batch", {"rows_used": 0.5})):
-            low = train_driver.reference(c, job, seed, **kw)
+            low = train_driver.reference(fam, c, job, seed, **kw)
             out[name] = {k: v for k, v in output_check.train_numbers(low, ref).items()}
     return out
 

@@ -1,12 +1,14 @@
 """What every cell shares: the spec read from ``BENCHMARK.json`` and the
-files it names, the program's model configuration, the device check, the
+files it names (the model's family among them), the device check, the
 compile cache, host spans, and the counters read around the window."""
 
 from __future__ import annotations
 
 import contextlib
+import importlib.util
 import json
 import os
+import re
 from pathlib import Path
 
 HERE = Path(__file__).resolve().parent
@@ -20,17 +22,24 @@ WINDOW_COUNTERS = (
 
 
 def load_spec(workload: str, root: Path = ROOT) -> dict:
+    """A cell and the files it names, found under ``root`` (a checkout);
+    its configuration's family file is loaded, as ``spec["family"]``,
+    before anything is built."""
+
     bench = json.loads((root / "BENCHMARK.json").read_text())
+    here = root / HERE.relative_to(ROOT)
     cells = {w["name"]: w for w in bench["workloads"]}
     if workload not in cells:
         raise KeyError(f"no workload {workload!r} in BENCHMARK.json")
     cell = cells[workload]
     conf = {c["name"]: c for c in bench["configs"]}[cell["config"]]
+    config = json.loads((root / conf["file"]).read_text())
     return {
         "cell": cell,
-        "config": json.loads((root / conf["file"]).read_text()),
-        "traffic": json.loads((HERE / "traffic" / f"{cell['traffic']}.json").read_text()),
-        "limits": json.loads((HERE / "limits" / f"{workload}.json").read_text()),
+        "config": config,
+        "family": family(config, here),
+        "traffic": json.loads((here / "traffic" / f"{cell['traffic']}.json").read_text()),
+        "limits": json.loads((here / "limits" / f"{workload}.json").read_text()),
         "end_to_end": [m for m in bench["end_to_end"]
                        if workload in m.get("workloads", [workload])],
         "per_layer": [m for m in bench["per_layer"]
@@ -38,34 +47,22 @@ def load_spec(workload: str, root: Path = ROOT) -> dict:
     }
 
 
-def program_config(c: dict):
-    """The program's ModelConfig for a configuration file.  Refuses what
-    the program cannot run as the file states it."""
+def family(config: dict, here: Path = HERE):
+    """The family file that a configuration names, ``families/<family>.py``
+    loaded by path; its interface is in ``families/dense_gqa.py``.  Refused
+    where the configuration names no family or the file does not exist."""
 
-    from repro.configs.base import ModelConfig
-
-    import math
-
-    if c.get("partial_rotary_factor", 1.0) != 1.0 or c.get("rope_scaling"):
-        raise ValueError("the program rotates whole heads with plain RoPE")
-    plain = {"embedding_multiplier": 1.0, "residual_multiplier": 1.0, "logits_scaling": 1.0,
-             "attention_multiplier": 1.0 / math.sqrt(c["head_dim"])}
-    for k, v in plain.items():
-        if not math.isclose(c.get(k, v), v, rel_tol=1e-12):
-            raise ValueError(f"the program has no {k}; it runs {v}")
-    cfg = ModelConfig(
-        name=c["name"], family="dense", num_layers=c["num_hidden_layers"],
-        d_model=c["hidden_size"], num_heads=c["num_attention_heads"],
-        num_kv_heads=c["num_key_value_heads"], head_dim=c["head_dim"],
-        d_ff=c["intermediate_size"], vocab_size=c["vocab_size"],
-        tie_embeddings=c["tie_word_embeddings"], rope_theta=c["rope_theta"],
-        act=c["hidden_act"], norm_eps=c["rms_norm_eps"], dtype=c["torch_dtype"],
-        source=c["source"],
-    )
-    if cfg.padded_vocab != c["padded_vocab_size"]:
-        raise ValueError(f"the program pads the vocabulary to {cfg.padded_vocab}, "
-                         f"the file says {c['padded_vocab_size']}")
-    return cfg
+    name = config.get("family")
+    if not isinstance(name, str) or not re.fullmatch(r"[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}", name):
+        raise ValueError(f"configuration {config.get('name')!r} names no family")
+    path = here / "families" / f"{name}.py"
+    if not path.is_file():
+        raise FileNotFoundError(f"configuration {config.get('name')!r} names family "
+                                f"{name!r}, and there is no {path}")
+    spec = importlib.util.spec_from_file_location(f"bench_family_{name}", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
 
 def parallel_config(c: dict):

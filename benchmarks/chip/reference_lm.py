@@ -4,7 +4,8 @@ head, grouped-query causal attention, a SwiGLU MLP, a tied embedding as the
 head, and the softmax over every row of the (padded) embedding.
 
 It imports nothing of the program.  Weights come from
-:mod:`bench_weights` one layer at a time.  Every matrix product runs at
+:mod:`bench_weights` one layer at a time, under the names, shapes and
+scales of :func:`weights`.  Every matrix product runs at
 ``Precision.HIGHEST``; with ``fp8=True`` the products of the projections,
 the MLP and the head take float8 (e4m3) operands instead, which is the
 control: the same model one precision step below bfloat16.
@@ -13,6 +14,7 @@ control: the same model one precision step below bfloat16.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -22,6 +24,32 @@ import bench_weights as bw
 
 HI = jax.lax.Precision.HIGHEST
 F8_MAX = 448.0
+EMBED_STD = 0.02
+NORM_STD = 0.02
+LAYER_KEYS = ("ln_attn", "wq", "wk", "wv", "wo", "ln_mlp", "w_gate", "w_up", "w_down")
+NORMS = ("ln_attn", "ln_mlp", "final_norm")
+
+
+def weights(c: dict) -> dict:
+    """Each weight's shape (one layer's, for a layer weight) and standard
+    deviation: ``{name: (shape, std)}``."""
+
+    d, H, Hk, dh, f = (c[k] for k in (
+        "hidden_size", "num_attention_heads", "num_key_value_heads", "head_dim",
+        "intermediate_size"))
+    shapes = {
+        "ln_attn": (d,), "wq": (d, H, dh), "wk": (d, Hk, dh), "wv": (d, Hk, dh),
+        "wo": (H, dh, d), "ln_mlp": (d,), "w_gate": (d, f), "w_up": (d, f),
+        "w_down": (f, d), "embed": (c["padded_vocab_size"], d), "final_norm": (d,),
+    }
+    fan_in = {"wo": H * dh, "w_down": f}
+    return {n: (s, NORM_STD if n in NORMS else EMBED_STD if n == "embed"
+                else 1.0 / math.sqrt(fan_in.get(n, d)))
+            for n, s in shapes.items()}
+
+
+def _draw(key, c: dict, name: str, layer):
+    return bw.draw(key, name, layer, *weights(c)[name])
 
 
 def _fp8(x, axis):
@@ -47,7 +75,7 @@ def mm(spec: str, x, w, fp8: bool):
 def layer(key, c: dict, i) -> dict:
     """Layer ``i``'s weights in float32, norms as ``1 + delta``."""
 
-    w = {n: bw.draw(key, c, n, i).astype(jnp.float32) for n in bw.LAYER_KEYS}
+    w = {n: _draw(key, c, n, i).astype(jnp.float32) for n in LAYER_KEYS}
     w["ln_attn"] = 1.0 + w["ln_attn"]
     w["ln_mlp"] = 1.0 + w["ln_mlp"]
     return w
@@ -71,7 +99,9 @@ def rope(x, theta: float, factor: float):
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin, rest], -1)
 
 
-def block(w: dict, x, c: dict, fp8: bool):
+def attention(w: dict, x, c: dict, fp8: bool):
+    """The attention half of a block, residual included."""
+
     eps = c["rms_norm_eps"]
     H, Hk, dh = c["num_attention_heads"], c["num_key_value_heads"], c["head_dim"]
     h = rms(x, w["ln_attn"], eps)
@@ -87,12 +117,19 @@ def block(w: dict, x, c: dict, fp8: bool):
     causal = jnp.arange(T)[:, None] >= jnp.arange(T)[None, :]
     s = jnp.where(causal, s, -jnp.inf)
     a = jnp.einsum("bhqs,bshk->bqhk", jax.nn.softmax(s, -1), v, precision=HI)
-    res = c.get("residual_multiplier", 1.0)
-    x = x + res * mm("bthk,hkd->btd", a, w["wo"], fp8)
-    h = rms(x, w["ln_mlp"], eps)
+    return x + c.get("residual_multiplier", 1.0) * mm("bthk,hkd->btd", a, w["wo"], fp8)
+
+
+def swiglu(w: dict, h, fp8: bool):
     g = mm("btd,df->btf", h, w["w_gate"], fp8)
     u = mm("btd,df->btf", h, w["w_up"], fp8)
-    return x + res * mm("btf,fd->btd", jax.nn.silu(g) * u, w["w_down"], fp8)
+    return mm("btf,fd->btd", jax.nn.silu(g) * u, w["w_down"], fp8)
+
+
+def block(w: dict, x, c: dict, fp8: bool):
+    x = attention(w, x, c, fp8)
+    h = rms(x, w["ln_mlp"], c["rms_norm_eps"])
+    return x + c.get("residual_multiplier", 1.0) * swiglu(w, h, fp8)
 
 
 def logits(x, embed, final_norm, c: dict, fp8: bool):
@@ -107,7 +144,7 @@ def embed_tokens(embed, tokens, c: dict):
 @functools.partial(jax.jit, static_argnames=("c_items",))
 def _embed_rows(key, tokens, c_items):
     c = dict(c_items)
-    return embed_tokens(bw.draw(key, c, "embed", 0).astype(jnp.float32), tokens, c)
+    return embed_tokens(_draw(key, c, "embed", 0).astype(jnp.float32), tokens, c)
 
 
 def _items(c: dict) -> tuple:
@@ -139,8 +176,8 @@ def hidden(seed: int, c: dict, seqs: np.ndarray, fp8: bool = False, chunk: int =
 @functools.partial(jax.jit, static_argnames=("c_items",))
 def _head_weights(key, c_items):
     c = dict(c_items)
-    e = bw.draw(key, c, "embed", 0).astype(jnp.float32)
-    return e, 1.0 + bw.draw(key, c, "final_norm", 0).astype(jnp.float32)
+    e = _draw(key, c, "embed", 0).astype(jnp.float32)
+    return e, 1.0 + _draw(key, c, "final_norm", 0).astype(jnp.float32)
 
 
 @functools.partial(jax.jit, static_argnames=("c_items", "fp8"))
@@ -183,7 +220,7 @@ def _nest(flat: dict, c: dict) -> dict:
 
     return {
         "embed": flat["embed"], "final_norm": flat["final_norm"],
-        "layers": [{n: flat[f"{n}.{i}"] for n in bw.LAYER_KEYS}
+        "layers": [{n: flat[f"{n}.{i}"] for n in LAYER_KEYS}
                    for i in range(c["num_hidden_layers"])],
     }
 
@@ -195,8 +232,8 @@ def params(seed: int, c: dict) -> dict:
     ci = _items(c)
     lay = jax.jit(lambda k, i: layer(k, dict(ci), i))
     out = {
-        "embed": bw.draw(key, c, "embed", 0).astype(jnp.float32),
-        "final_norm": 1.0 + bw.draw(key, c, "final_norm", 0).astype(jnp.float32),
+        "embed": _draw(key, c, "embed", 0).astype(jnp.float32),
+        "final_norm": 1.0 + _draw(key, c, "final_norm", 0).astype(jnp.float32),
     }
     for i in range(c["num_hidden_layers"]):
         out.update({f"{n}.{i}": w for n, w in lay(key, i).items()})
@@ -238,10 +275,19 @@ def train(seed: int, c: dict, batches: list, hp: dict, fp8: bool = False,
     fits beside nothing at the depth the program trains."""
 
     cc = dict(_items(c))
-    p = params(seed, c)
+    return adamw(lambda: params(seed, c), lambda p, toks: row_loss(p, toks, cc, fp8),
+                 batches, hp, rows_used)
+
+
+def adamw(init, loss, batches: list, hp: dict, rows_used: float = 1.0) -> dict:
+    """The trainer's AdamW over ``batches``, as :func:`train` describes it,
+    for any model: ``init()`` gives its weights flat in float32 (it is
+    called again for the change), ``loss(p, tokens)`` its mean loss."""
+
+    p = init()
     m = {k: np.zeros(x.shape, np.float32) for k, x in p.items()}
     v = {k: np.zeros(x.shape, np.float32) for k, x in p.items()}
-    grad = jax.jit(jax.value_and_grad(lambda p, toks: row_loss(p, toks, cc, fp8)))
+    grad = jax.jit(jax.value_and_grad(loss))
     norm = jax.jit(lambda g: jnp.sqrt(sum(jnp.sum(x * x) for x in jax.tree.leaves(g))))
 
     @functools.partial(jax.jit, donate_argnums=(0, 2, 3))
@@ -257,8 +303,8 @@ def train(seed: int, c: dict, batches: list, hp: dict, fp8: bool = False,
     for t, toks in enumerate(batches, start=1):
         toks = np.asarray(toks)
         toks = toks[: max(1, int(round(len(toks) * rows_used)))]
-        loss, g = grad(p, jnp.asarray(toks))
-        losses.append(float(loss))
+        value, g = grad(p, jnp.asarray(toks))
+        losses.append(float(value))
         scale = jnp.minimum(1.0, hp["clip_norm"] / jnp.maximum(norm(g), 1e-12))
         gn = {}
         for k in list(p):
@@ -267,6 +313,6 @@ def train(seed: int, c: dict, batches: list, hp: dict, fp8: bool = False,
             m[k], v[k] = np.asarray(mk), np.asarray(vk)
         if first is None:
             first = {k: float(x) for k, x in gn.items()}
-    start = params(seed, c)
+    start = init()
     change = {k: float(jnp.linalg.norm((p[k] - start.pop(k)).ravel())) for k in list(p)}
     return {"losses": losses, "grad_norms": first, "change_norms": change}

@@ -49,15 +49,16 @@ def check(spec: dict, res: dict, seed: int) -> dict:
     """The numbers the limits hold, from the reference once the window has
     closed and the program's state is freed."""
 
-    c, mix = spec["config"], spec["traffic"]
+    c, mix, fam = spec["config"], spec["traffic"], spec["family"]
     if mix["driver"] == "serve":
         import serve_driver
 
-        return serve_driver.reference_numbers(c, mix, seed, res["picked"], res["last_step"])
+        return serve_driver.reference_numbers(fam, c, mix, seed, res["picked"],
+                                              res["last_step"])
     import output_check
     import train_driver
 
-    ref = train_driver.reference(c, mix, seed)
+    ref = train_driver.reference(fam, c, mix, seed)
     return output_check.train_numbers(res["check"], ref)
 
 
@@ -67,7 +68,6 @@ def run_cell(spec: dict, seed: int, seconds: float, traced: bool, t0: float,
     fault in the system under test (tests only).  Metrics are reported only
     for an accelerator."""
 
-    import model_flops
     import output_check
     from chip_peaks import PEAKS
 
@@ -107,7 +107,7 @@ def run_cell(spec: dict, seed: int, seconds: float, traced: bool, t0: float,
         harness.note(programs=tr["programs"], spans=tr["spans"],
                      collective_s=tr["collective_s"])
         ctx = {"trace": tr, "window": res["window"], "config": spec["config"],
-               "traffic": mix, "chips": spec["cell"]["chips"], "flops": model_flops,
+               "traffic": mix, "chips": spec["cell"]["chips"], "flops": spec["family"],
                "peaks": PEAKS.get(device["kind"])}
         for m in spec["per_layer"]:
             v = reader(m["name"])(ctx) if on_chip else None

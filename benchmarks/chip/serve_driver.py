@@ -30,7 +30,7 @@ import harness
 import traffic_gen
 
 
-def build(c: dict, mix: dict, seed: int):
+def build(fam, c: dict, mix: dict, seed: int):
     from repro.launch.mesh import make_host_communicator
     from repro.runtime.engine import Engine, EngineConfig
     from repro.runtime.server import Server, ServerConfig
@@ -39,8 +39,8 @@ def build(c: dict, mix: dict, seed: int):
         max_batch=mix["slots"], max_new_tokens=mix["output_len"]["max"],
         temperature=0.0, seed=bw.weight_seed(seed),
     )
-    with bw.program_weights(c):
-        server = Server(harness.program_config(c), harness.parallel_config(c), scfg,
+    with bw.program_weights(fam, c):
+        server = Server(fam.program_config(c), harness.parallel_config(c), scfg,
                         make_host_communicator(1, 1))
     engine = Engine(server, EngineConfig(prompt_bucket=mix["prompt_bucket"],
                                          block_tokens=mix["block_tokens"]))
@@ -196,12 +196,12 @@ def _teacher_forced(mix: dict, picked: list):
     return seqs
 
 
-def reference_numbers(c: dict, mix: dict, seed: int, picked: list, last: dict | None,
-                      control: bool = False) -> dict:
+def reference_numbers(fam, c: dict, mix: dict, seed: int, picked: list,
+                      last: dict | None, control: bool = False) -> dict:
     """The numbers the limits hold, from one teacher-forced pass of the
-    reference over the sampled requests and the last decode step's: each
-    prompt left-padded with zeros to the bucket (as the engine serves it),
-    then its served tokens (all but the last).
+    family's reference over the sampled requests and the last decode
+    step's: each prompt left-padded with zeros to the bucket (as the engine
+    serves it), then its served tokens (all but the last).
 
     - ``max_gap``: the widest gap by which a served token's reference logit
       lies below the reference's best, over every served token sampled;
@@ -213,13 +213,11 @@ def reference_numbers(c: dict, mix: dict, seed: int, picked: list, last: dict | 
     gaps of the tokens it ranks first, its logits), and for each served
     token altered to the next id (``altered_max_gap``)."""
 
-    import reference_lm
-
     bucket = mix["prompt_bucket"]
     lasts = last["requests"] if last else []
     seqs = _teacher_forced(mix, picked + lasts)
     sides = [False, True] if control else [False]
-    xs = {fp8: reference_lm.hidden(seed, c, seqs, fp8=fp8) for fp8 in sides}
+    xs = {fp8: fam.hidden(seed, c, seqs, fp8=fp8) for fp8 in sides}
     out = {}
     if picked:
         rows, served = [], []
@@ -230,11 +228,11 @@ def reference_numbers(c: dict, mix: dict, seed: int, picked: list, last: dict | 
         rows = np.asarray(rows, np.int32)
         cand = {"served": np.asarray(served, np.int32)}
         if control:
-            low = reference_lm.score(seed, c, xs[True][rows[:, 0], rows[:, 1]], {}, fp8=True)
+            low = fam.score(seed, c, xs[True][rows[:, 0], rows[:, 1]], {}, fp8=True)
             cand["control"] = low["top"].astype(np.int32)
             nxt = cand["served"] + 1
             cand["altered"] = np.where(nxt < c["vocab_size"], nxt, 1).astype(np.int32)
-        ref = reference_lm.score(seed, c, xs[False][rows[:, 0], rows[:, 1]], cand)
+        ref = fam.score(seed, c, xs[False][rows[:, 0], rows[:, 1]], cand)
         gaps = {k: ref["best"] - ref[k] for k in cand}
         out["max_gap"] = float(gaps["served"].max())
         out["_served_tokens"] = int(gaps["served"].size)
@@ -244,13 +242,13 @@ def reference_numbers(c: dict, mix: dict, seed: int, picked: list, last: dict | 
     if lasts:
         rows = np.asarray([(len(picked) + i, bucket - 2 + len(p["served"]))
                            for i, p in enumerate(lasts)], np.int32)
-        ref = np.asarray(reference_lm.head(seed, c, xs[False][rows[:, 0], rows[:, 1]]))
+        ref = np.asarray(fam.head(seed, c, xs[False][rows[:, 0], rows[:, 1]]))
         scale = np.abs(ref).max(-1)
         err = lambda got: float((np.abs(got - ref).max(-1) / scale).max())
         out["logit_err"] = err(last["logits"])
         out["_last_step_rows"] = len(lasts)
         if control:
-            low = reference_lm.head(seed, c, xs[True][rows[:, 0], rows[:, 1]], fp8=True)
+            low = fam.head(seed, c, xs[True][rows[:, 0], rows[:, 1]], fp8=True)
             out["control_logit_err"] = err(np.asarray(low))
     return out
 
@@ -261,7 +259,7 @@ def run(spec: dict, seed: int, seconds: float, traced: bool, setup_t0: float,
 
     c, mix = spec["config"], spec["traffic"]
     counters = harness.Counters()
-    server, engine = build(c, mix, seed)
+    server, engine = build(spec["family"], c, mix, seed)
     rows = warm(engine, c["vocab_size"])
     if traced:
         seconds = min(seconds, mix["trace_seconds"])

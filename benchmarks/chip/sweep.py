@@ -42,7 +42,7 @@ def main(argv=None) -> int:
     from repro.runtime.engine import Engine
 
     c, mix = spec["config"], spec["traffic"]
-    server, engine = serve_driver.build(c, mix, args.seed)
+    server, engine = serve_driver.build(spec["family"], c, mix, args.seed)
     serve_driver.warm(engine, c["vocab_size"])
     ecfg = engine.ecfg
     for rate in [float(r) for r in args.rates.split(",")]:

@@ -19,6 +19,7 @@ HERE = Path(__file__).resolve().parent
 sys.path.insert(0, str(HERE))
 sys.path.insert(1, str(HERE.parents[1] / "src"))
 
+import harness  # noqa: E402
 import run as bench_run  # noqa: E402
 
 TINY = {
@@ -27,7 +28,7 @@ TINY = {
     "head_dim": 16, "vocab_size": 500, "padded_vocab_size": 512,
     "tie_word_embeddings": True, "rope_theta": 10000.0, "partial_rotary_factor": 1.0,
     "rope_scaling": None, "rms_norm_eps": 1e-6, "hidden_act": "silu",
-    "torch_dtype": "bfloat16", "program": {"arch": "phi4_mini_3_8b"},
+    "torch_dtype": "bfloat16", "program": {"arch": "phi4_mini_3_8b"}, "family": "dense_gqa",
 }
 CPU = {"platform": "cpu", "kind": "cpu", "count": 1}
 SEED = 2**33 + 17
@@ -46,8 +47,8 @@ def serve_spec(mix_name: str) -> dict:
     # a token altered reads 0.6-0.7 here (4.6-6.3 on the chip), sound runs
     # 0-0.002; its own limit between them
     limits["max_gap"] = {"limit": 0.3}
-    return {"cell": {"chips": 1}, "config": TINY, "traffic": mix, "limits": limits,
-            "end_to_end": [], "per_layer": []}
+    return {"cell": {"chips": 1}, "config": TINY, "family": harness.family(TINY),
+            "traffic": mix, "limits": limits, "end_to_end": [], "per_layer": []}
 
 
 def train_spec() -> dict:
@@ -58,13 +59,13 @@ def train_spec() -> dict:
     # cell's (sound runs here read ~1.4e-3): its own limit, between that and
     # the faults below
     limits["grad_gap"] = {"limit": 0.01}
-    return {"cell": {"chips": 1}, "config": TINY, "traffic": job, "limits": limits,
-            "end_to_end": [], "per_layer": []}
+    return {"cell": {"chips": 1}, "config": TINY, "family": harness.family(TINY),
+            "traffic": job, "limits": limits, "end_to_end": [], "per_layer": []}
 
 
 def cell(spec, fault=None, seconds=1.5):
-    return bench_run.run_cell(copy.deepcopy(spec), SEED, seconds, False,
-                              time.perf_counter(), CPU, fault)
+    fresh = copy.deepcopy(spec, {id(spec["family"]): spec["family"]})   # modules stay shared
+    return bench_run.run_cell(fresh, SEED, seconds, False, time.perf_counter(), CPU, fault)
 
 
 def quiet_window(capsys) -> dict:

@@ -49,7 +49,7 @@ def hyper(job: dict) -> dict:
                                 "weight_decay", "b1", "b2", "eps")}
 
 
-def build(c: dict, job: dict, seed: int, chips: int):
+def build(fam, c: dict, job: dict, seed: int, chips: int):
     from repro.launch.mesh import make_host_communicator
     from repro.runtime.faults import StragglerPolicy
     from repro.runtime.trainer import Trainer, TrainerConfig
@@ -65,8 +65,8 @@ def build(c: dict, job: dict, seed: int, chips: int):
     # of a second or two, seen now and then on the chip's host) by starting
     # again from step 0, which ends a measured window; a step that does not
     # straggle pays the same either way.
-    with bw.program_weights(c):
-        trainer = Trainer(harness.program_config(c), harness.parallel_config(c), tcfg,
+    with bw.program_weights(fam, c):
+        trainer = Trainer(fam.program_config(c), harness.parallel_config(c), tcfg,
                           comm, seq_len=job["seq_len"], global_batch=job["batch"],
                           straggler=StragglerPolicy(deadline_factor=math.inf))
     if (trainer.opt.b1, trainer.opt.b2, trainer.opt.eps) != (job["b1"], job["b2"], job["eps"]):
@@ -75,45 +75,42 @@ def build(c: dict, job: dict, seed: int, chips: int):
     return trainer
 
 
-@jax.jit
-def _norms(tree):
+def _norms(tree, fam, c: dict):
     """Norm of each leaf in float32; a stacked leaf gets one per layer."""
 
     def one(path, x):
         x = x.astype(jnp.float32)
-        if getattr(path[0], "key", None) == "layers":
+        if bw.leaf(fam, c, path).stacked:
             return jnp.sqrt(jnp.sum(x * x, axis=tuple(range(1, x.ndim))))
         return jnp.linalg.norm(x.ravel())
 
     return jax.tree_util.tree_map_with_path(one, tree)
 
 
-def _keyed(norms, scale: float = 1.0) -> dict:
-    """Per-weight norms keyed as the reference keys them: ``name`` or
-    ``name.layer``."""
+def _keyed(norms, fam, c: dict, scale: float = 1.0) -> dict:
+    """Per-weight norms keyed as the family's reference keys them, by
+    :func:`bench_weights.check_key`: ``name`` or ``name.layer``."""
 
     out = {}
     for path, v in jax.tree_util.tree_flatten_with_path(norms)[0]:
-        names = [getattr(p, "key", None) for p in path]
+        lf = bw.leaf(fam, c, path)
         v = np.asarray(v)
-        if names[0] == "layers":
-            for i, x in enumerate(v):
-                out[f"{names[-1]}.{i}"] = float(x) * scale
-        else:
-            out[names[-1]] = float(v) * scale
+        for i, x in enumerate(v if lf.stacked else [v]):
+            out[bw.check_key(lf, i)] = float(x) * scale
     return out
 
 
 def run(spec: dict, seed: int, seconds: float, traced: bool, setup_t0: float,
         fault=None) -> dict:
-    c, job, chips = spec["config"], spec["traffic"], spec["cell"]["chips"]
+    c, job, chips, fam = spec["config"], spec["traffic"], spec["cell"]["chips"], spec["family"]
     counters = harness.Counters()
-    trainer = build(c, job, seed, chips)
+    trainer = build(fam, c, job, seed, chips)
     if fault is not None:
         fault(trainer)
     params, opt_state = trainer.init_state()
     trainer.compile(params, opt_state)
-    start = jax.jit(bw.program_init(jax.eval_shape(lambda: params), c))
+    start = jax.jit(bw.program_init(jax.eval_shape(lambda: params), fam, c))
+    norms = jax.jit(lambda tree: _norms(tree, fam, c))
 
     # the first steps, through the trainer's own loop; the check reads the
     # optimizer's first moment after step 1 (the first gradient, as the
@@ -123,12 +120,12 @@ def run(spec: dict, seed: int, seconds: float, traced: bool, setup_t0: float,
     while step < CHECK_STEPS:
         params, opt_state, step = trainer._run_span(params, opt_state, step, step + 1)
         if step == 1:
-            check["grad_norms"] = _keyed(_norms(opt_state.mu), 1.0 / (1.0 - job["b1"]))
+            check["grad_norms"] = _keyed(norms(opt_state.mu), fam, c, 1.0 / (1.0 - job["b1"]))
     # the change's norms in one program, so that no second copy of the
     # weights is held beside the training state
     change = jax.jit(lambda p, k: _norms(jax.tree.map(
-        lambda a, b: a.astype(jnp.float32) - b.astype(jnp.float32), p, start(k))))
-    check["change_norms"] = _keyed(change(params, bw.seed_key(seed)))
+        lambda a, b: a.astype(jnp.float32) - b.astype(jnp.float32), p, start(k)), fam, c))
+    check["change_norms"] = _keyed(change(params, bw.seed_key(seed)), fam, c)
     check["losses"] = [m["loss"] for m in trainer.metrics_history[:CHECK_STEPS]]
     jax.block_until_ready(params)
 
@@ -172,12 +169,11 @@ def run(spec: dict, seed: int, seconds: float, traced: bool, setup_t0: float,
     }
 
 
-def reference(c: dict, job: dict, seed: int, fp8: bool = False, rows_used: float = 1.0) -> dict:
-    import reference_lm
-
+def reference(fam, c: dict, job: dict, seed: int, fp8: bool = False,
+              rows_used: float = 1.0) -> dict:
     feed = Feed(seed, job["batch"], job["seq_len"], c["vocab_size"])
     batches = [feed.host_batch(s)["tokens"] for s in range(CHECK_STEPS)]
-    return reference_lm.train(seed, c, batches, hyper(job), fp8=fp8, rows_used=rows_used)
+    return fam.train(seed, c, batches, hyper(job), fp8=fp8, rows_used=rows_used)
 
 
 def end_to_end(res: dict, job: dict) -> dict:
